@@ -1483,8 +1483,10 @@ def main(argv=None) -> int:
                  "window would hit the position bound)")
 
     import jax
+    from repro.launch.compile_cache import enable_compilation_cache
     from repro.launch.mesh import make_host_mesh
 
+    enable_compilation_cache()
     cfg, rc, params = _build(args.arch, args.seed, args.vocab, args.dtype)
     kw = dict(n_slots=n_slots, max_seq=max_seq, prompt_len=prompt_len,
               max_new=max_new, n_requests=n_slots,

@@ -1,7 +1,1 @@
-"""repro: CXL-GPU reproduction package.
-
-Importing the package installs the jax < 0.5 compatibility shims so every
-entry point (tests, benchmarks, examples, launch scripts) sees the same
-jax sharding surface regardless of the installed jax version.
-"""
-from repro import _compat  # noqa: F401  (side effect: install shims)
+"""repro: CXL-GPU reproduction package."""

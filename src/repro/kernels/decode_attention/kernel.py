@@ -49,9 +49,14 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *refs, page: int,
         v = v_ref[0, 0, 0]                 # [page, D]
         if quant:
             # int8 pages: dequantize in-kernel with this page's fp32
-            # scale (scalar per (b, hkv, page)); math stays f32
-            k = k.astype(jnp.float32) * ks_ref[0, 0, 0]
-            v = v.astype(jnp.float32) * vs_ref[0, 0, 0]
+            # scale, picked out of the head's [1, P] scale row by a
+            # masked lane reduction; math stays f32
+            sel = jax.lax.broadcasted_iota(
+                jnp.int32, ks_ref.shape[2:], 1) == pj
+            k = k.astype(jnp.float32) * jnp.sum(
+                jnp.where(sel, ks_ref[0, 0], 0.0), axis=1, keepdims=True)
+            v = v.astype(jnp.float32) * jnp.sum(
+                jnp.where(sel, vs_ref[0, 0], 0.0), axis=1, keepdims=True)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # [G, page]
@@ -106,8 +111,11 @@ def paged_flash_decode(q: jnp.ndarray, k_pages: jnp.ndarray,
     def page_map(bi, hi, pj, len_ref):
         return (bi, hi, pj, 0, 0)
 
+    # a head's scales ride as one full-extent [1, P] row (a block of one
+    # scale would break the TPU's (8, 128) tiling); it is fetched once
+    # per (b, hkv) since its block index does not move with the page
     def scale_map(bi, hi, pj, len_ref):
-        return (bi, hi, pj)
+        return (bi, hi, 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, g, d),
@@ -117,10 +125,10 @@ def paged_flash_decode(q: jnp.ndarray, k_pages: jnp.ndarray,
     ]
     operands = [kv_len, q, k_pages, v_pages]
     if quant:
-        in_specs += [pl.BlockSpec((1, 1, 1), scale_map),
-                     pl.BlockSpec((1, 1, 1), scale_map)]
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        in_specs += [pl.BlockSpec((1, 1, 1, n_pages), scale_map),
+                     pl.BlockSpec((1, 1, 1, n_pages), scale_map)]
+        operands += [k_scale.astype(jnp.float32)[:, :, None],
+                     v_scale.astype(jnp.float32)[:, :, None]]
 
     return pl.pallas_call(
         kernel,

@@ -6,11 +6,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.decode_attention.kernel import paged_flash_decode
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("logit_softcap",))
@@ -32,5 +29,5 @@ def decode(q, k_pages, v_pages, kv_len, *, logit_softcap: float = 0.0,
     ks = None if k_scale is None else jnp.moveaxis(k_scale, 2, 1)
     vs = None if v_scale is None else jnp.moveaxis(v_scale, 2, 1)
     o = paged_flash_decode(qk, kp, vp, kv_len, logit_softcap=logit_softcap,
-                           interpret=_interpret(), k_scale=ks, v_scale=vs)
+                           interpret=interpret_mode(), k_scale=ks, v_scale=vs)
     return o.reshape(b, 1, h, d)

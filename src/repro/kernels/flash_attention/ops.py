@@ -1,8 +1,7 @@
 """Jitted public wrapper: model layout <-> kernel layout adaptation.
 
-On non-TPU backends the kernel body runs under ``interpret=True`` so the
-same code path is validated everywhere; the TPU target compiles the Mosaic
-kernel.
+On the CPU backend the kernel body runs under ``interpret=True``; a TPU
+compiles the Mosaic kernel (see ``repro.kernels.interpret_mode``).
 """
 from __future__ import annotations
 
@@ -11,11 +10,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.kernel import flash_attention
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "q_block",
@@ -31,5 +27,5 @@ def attention(q, k, v, *, causal: bool = True, q_block: int = 256,
     vk = jnp.moveaxis(v, 1, 2)
     o = flash_attention(qk, kk, vk, causal=causal, q_block=q_block,
                         kv_block=kv_block, logit_softcap=logit_softcap,
-                        interpret=_interpret())
+                        interpret=interpret_mode())
     return jnp.moveaxis(o, 3, 1).reshape(b, s, h, d)

@@ -6,11 +6,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.hdm_stream.kernel import paged_matmul
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n"))
@@ -18,4 +15,4 @@ def stream_matmul(x, w_pages, page_ids, *, block_m: int = 256,
                   block_n: int = 256):
     """y = x @ vstack(w_pages[page_ids]). See kernel.py."""
     return paged_matmul(x, w_pages, page_ids, block_m=block_m,
-                        block_n=block_n, interpret=_interpret())
+                        block_n=block_n, interpret=interpret_mode())

@@ -6,11 +6,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.mamba2_scan.kernel import ssd_scan
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
@@ -30,5 +27,5 @@ def ssd(xdt, bmat, cmat, log_a, *, chunk: int = 256):
     la = jnp.cumsum(log_a.reshape(b, c, chunk, h), axis=2)
     la = jnp.moveaxis(la, 3, 1)                               # [B,H,C,Q]
     y = ssd_scan(xk.astype(jnp.float32), bk, ck, la,
-                 interpret=_interpret())
+                 interpret=interpret_mode())
     return jnp.moveaxis(y, 1, 3).reshape(b, s, h, p)

@@ -8,8 +8,6 @@ while tests and benches see the single real CPU device.
 from __future__ import annotations
 
 import jax
-
-import repro._compat  # noqa: F401  (jax < 0.5: installs AxisType et al.)
 from jax.sharding import AxisType, Mesh
 
 

@@ -37,17 +37,20 @@ hot-removed ports, and requests re-queued through RECOVERING.
 and the paged KV cache over the model axis, and (with a tier attached)
 splits the topology into one root-port set per rank with cross-rank
 restores charged on a peer link. Faults then apply to rank 0's ports.
+Without ``--tp`` the engine runs on one device: the first one JAX finds.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 
 import jax
 
 from repro.configs import registry
 from repro.configs.base import MeshConfig, RunConfig, SHAPES
-from repro.launch.mesh import make_host_mesh, make_production_mesh
+from repro.launch.compile_cache import enable_compilation_cache
+from repro.launch.mesh import make_host_mesh
 from repro.models import model as M
 from repro.serving.config import ServeConfig
 from repro.serving.engine import Request, ServingEngine
@@ -172,6 +175,36 @@ def _print_tier(engine, config):
                   f"{p['inflight']} in flight")
 
 
+def host_mesh_scope(config: ServeConfig):
+    """The mesh context an engine built from ``config`` is driven under:
+    the one-device host mesh when unsharded, none for ``tp > 1``."""
+    if config.n_ranks > 1:
+        return contextlib.nullcontext()
+    return jax.set_mesh(make_host_mesh())
+
+
+def build_engine(arch: str, *, smoke: bool,
+                 config: ServeConfig) -> ServingEngine:
+    """The engine ``serve`` drives: ``arch`` at full width (``smoke``:
+    the reduced registry config) with seeded random weights.
+
+    An unsharded engine runs on the mesh its caller activates: build and
+    drive it under :func:`host_mesh_scope`. A ``tp > 1`` engine activates
+    its own (1, tp) mesh around each dispatch.
+    """
+    cfg = registry.smoke(arch) if smoke else registry.get(arch)
+    rc = RunConfig(model=cfg, shape=SHAPES["decode_32k"], mesh=MeshConfig())
+    if config.n_ranks > 1:
+        # sharded decode needs the page axis divisible by the model
+        # axis: cap the page size so each slot has >= n_ranks pages
+        import dataclasses as _dc
+        page = min(rc.kv_page_size, max(config.max_seq // config.n_ranks,
+                                        1))
+        rc = _dc.replace(rc, kv_page_size=page)
+    params = M.init_model(jax.random.PRNGKey(config.seed), cfg)
+    return ServingEngine(params, cfg, rc, config=config)
+
+
 def serve(arch: str, *, smoke: bool = True, n_requests: int = 8,
           max_new: int = 12, prompt_len: int = 6,
           config: ServeConfig = _DEF, load=None, max_ticks: int = 100_000):
@@ -186,19 +219,9 @@ def serve(arch: str, *, smoke: bool = True, n_requests: int = 8,
     tier media/topology, async I/O, preemption, admission mode — comes
     from ``config``. Returns ``(engine, finished_requests)``.
     """
-    cfg = registry.smoke(arch) if smoke else registry.get(arch)
-    mesh = make_host_mesh() if smoke else make_production_mesh()
-    rc = RunConfig(model=cfg, shape=SHAPES["decode_32k"], mesh=MeshConfig())
-    if config.n_ranks > 1:
-        # sharded decode needs the page axis divisible by the model
-        # axis: cap the page size so each slot has >= n_ranks pages
-        import dataclasses as _dc
-        page = min(rc.kv_page_size, max(config.max_seq // config.n_ranks,
-                                        1))
-        rc = _dc.replace(rc, kv_page_size=page)
-    with jax.set_mesh(mesh):
-        params = M.init_model(jax.random.PRNGKey(config.seed), cfg)
-        engine = ServingEngine(params, cfg, rc, config=config)
+    with host_mesh_scope(config):
+        engine = build_engine(arch, smoke=smoke, config=config)
+        cfg = engine.cfg
         if load is not None:
             from repro.serving.loadgen import (drive_open_loop, make_trace,
                                                summarize)
@@ -317,6 +340,7 @@ def main() -> None:
                          "e.g. XLA_FLAGS=--xla_force_host_platform_"
                          "device_count=N on CPU)")
     args = ap.parse_args()
+    enable_compilation_cache()
     topology = tuple(m.strip() for m in
                      args.cxl_topology.split(",") if m.strip())
     tier_faults = ()

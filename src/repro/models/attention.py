@@ -43,9 +43,6 @@ def qkv_project(params: Dict, cfg: ModelConfig, x: jnp.ndarray,
                 positions: jnp.ndarray, fuse_qkv: bool = True,
                 rope: bool = True):
     """x: [B, S, d] -> q [B,S,H,D], k/v [B,S,Hkv,D] with qk-norm + RoPE."""
-    from repro._compat.jax_compat import SHARDED_CONCAT_SAFE
-    if fuse_qkv and not SHARDED_CONCAT_SAFE:
-        fuse_qkv = False    # jax 0.4.x: sharded-axis concat is miscompiled
     if fuse_qkv:
         wqkv = jnp.concatenate([params["wq"], params["wk"], params["wv"]],
                                axis=1)

@@ -3,15 +3,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-import repro  # noqa: E402,F401  (installs the jax < 0.5 compat shims)
-
-try:
-    import hypothesis  # noqa: F401  (preferred when installed — CI does)
-except ImportError:
-    from repro._compat import hypothesis_fallback
-
-    hypothesis_fallback.register()
-
 import random  # noqa: E402
 
 import jax  # noqa: E402

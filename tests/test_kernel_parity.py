@@ -1,10 +1,8 @@
 """Property-style differential parity: every Pallas kernel package vs its
 pure-jnp oracle across randomly drawn shapes/dtypes/seeds.
 
-Runs under real hypothesis when installed (CI) and under the seeded
-fallback shim otherwise (``repro._compat.hypothesis_fallback``) — either
-way each test executes against many drawn examples, complementing the
-fixed-case sweep in ``test_kernels.py``. Kernels execute in interpret
+Each test executes against many hypothesis-drawn examples, complementing
+the fixed-case sweep in ``test_kernels.py``. Kernels execute in interpret
 mode on CPU (the same code path Mosaic compiles on TPU).
 
 Also covers the serving engine's dispatch split: the single-rank fast

@@ -11,9 +11,6 @@ Pins the three contracts the quantized tier path rests on:
  * the serving engine's flush -> restore -> decode path preserves the
    int8 payload byte-exactly and charges quantized (roughly halved)
    byte counts end-to-end.
-
-Runs under real hypothesis when installed (CI) and under the seeded
-fallback shim otherwise (``repro._compat.hypothesis_fallback``).
 """
 import dataclasses
 

@@ -119,7 +119,6 @@ def test_assemble_compiles_on_host_mesh(mesh_ctx, shape_name):
 _DISTRIBUTED_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    import repro  # installs the jax < 0.5 compat shims (AxisType, set_mesh)
     import jax, jax.numpy as jnp, numpy as np, json
     from jax.sharding import AxisType
     from repro.configs import registry
