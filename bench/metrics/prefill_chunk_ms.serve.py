@@ -1,0 +1,6 @@
+"""Mean device time of one prefill-chunk call in the traced window, ms."""
+from bench import readers
+
+
+def read(record):
+    return readers.program_ms(record, "prefill")
