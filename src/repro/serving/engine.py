@@ -62,6 +62,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import time
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -76,7 +77,13 @@ from repro.models import model as M
 from repro.parallel import sharding as shlib
 from repro.serving import scheduler as sched
 from repro.serving.config import ServeConfig
+from repro.serving.spans import SpanLog
 from repro.serving.stats import EngineStats
+
+# Names of the two jitted hot programs: a profile shows them as
+# "jit_<name>", and the benchmark's trace reduction finds them by these.
+DECODE_PROGRAM = "serve_decode_sample"
+PREFILL_PROGRAM = "serve_prefill_chunk_body"
 
 
 @dataclasses.dataclass
@@ -121,6 +128,8 @@ class Request:
     _start_tick: int = 0
     _n_gen: int = 0                 # total generated tokens (stop check)
     _n_dec: int = 0                 # decode ticks participated (trace span)
+    _queued_ns: Optional[int] = dataclasses.field(
+        default=None, repr=False)   # submit time while the span log is on
 
 
 class RequestHandle:
@@ -224,6 +233,15 @@ def layer_scan_unroll(cfg: ModelConfig, rc: RunConfig, n_slots: int,
                              and u * per_layer <= _UNROLL_CACHE_BYTES))
 
 
+def _named(fn, name: str):
+    """``fn`` under the function name ``name``, which jit gives the
+    program it compiles."""
+    def program(*args):
+        return fn(*args)
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
 def _fsdp_axis_size() -> int:
     """Product of the pool-tier (FSDP) mesh axes under the active mesh."""
     mesh = jax.sharding.get_abstract_mesh()
@@ -248,14 +266,17 @@ class HostPageStore:
     entry during its own insert (a re-staged rid growing past the budget,
     or any oversized entry), and indexing such an entry would leak — the
     eviction callback for it has already fired by the time ``put``
-    returns.
+    returns. ``spans`` times the device-to-host copy of each insert
+    (``serve.flush.copy``) while it is on.
     """
 
-    def __init__(self, budget_bytes: Optional[int] = None, on_evict=None):
+    def __init__(self, budget_bytes: Optional[int] = None, on_evict=None,
+                 spans: Optional[SpanLog] = None):
         self.pages: "collections.OrderedDict[int, Dict]" = \
             collections.OrderedDict()
         self.budget_bytes = budget_bytes
         self.on_evict = on_evict
+        self.spans = spans if spans is not None else SpanLog()
         self.bytes = 0
         self.evictions = 0
 
@@ -268,7 +289,13 @@ class HostPageStore:
         if not isinstance(entry, dict) or "kv" not in entry:
             entry = {"kv": entry}      # bare-pytree compat (pre-entry API)
         entry = dict(entry)
-        entry["kv"] = jax.tree_util.tree_map(np.asarray, entry["kv"])
+        # the captured pages wait on the decode that wrote them: block
+        # first, so that the copy span times the copy alone
+        jax.block_until_ready(entry["kv"])
+        with self.spans.span("serve.flush.copy") as attrs:
+            if attrs is not None:
+                attrs["bytes"] = self._entry_bytes(entry["kv"])
+            entry["kv"] = jax.tree_util.tree_map(np.asarray, entry["kv"])
         if rid in self.pages:
             old = self.pages.pop(rid)
             self.bytes -= self._entry_bytes(old)
@@ -431,8 +458,11 @@ class ServingEngine:
             self, async_restore=self.cxl_async,
             preempt_policy=config.preempt_policy,
             admit_mode=config.admit_mode)
+        # spans inside the engine, off until started (repro.serving.spans)
+        self.spans = SpanLog()
         self.store = HostPageStore(budget_bytes=config.store_budget_bytes,
-                                   on_evict=self._drop_prompt_alias)
+                                   on_evict=self._drop_prompt_alias,
+                                   spans=self.spans)
         self._prompt_index: Dict[Tuple[int, ...], int] = {}
         self.flusher = ds.StagingFlusher(
             sink=self._store_sink, qos=self.qos,
@@ -449,9 +479,11 @@ class ServingEngine:
         # tick (last_tokens/key are NOT donated — the token trace keeps
         # handles to old tick outputs until retirement).
         self.step_fn = jax.jit(self._step)                  # legacy decode
-        self._decode_fn = jax.jit(self._decode_sample, donate_argnums=(1,))
-        self._prefill_fn = jax.jit(self._prefill_chunk_body,
-                                   donate_argnums=(1,), static_argnums=(8,))
+        self._decode_fn = jax.jit(_named(self._decode_sample, DECODE_PROGRAM),
+                                  donate_argnums=(1,))
+        self._prefill_fn = jax.jit(
+            _named(self._prefill_chunk_body, PREFILL_PROGRAM),
+            donate_argnums=(1,), static_argnums=(8,))
         # typed stats: field list = schema (see repro.serving.stats). The
         # mapping protocol keeps every stats["..."] call site unchanged,
         # and a typo'd key raises KeyError instead of silently growing
@@ -556,6 +588,8 @@ class ServingEngine:
             if key is not None:
                 self.tier.speculative_read(
                     key, CxlTier.entry_bytes(self.store.pages[key]))
+        if self.spans.on:
+            req._queued_ns = time.perf_counter_ns()
         self.queue.append(req)
         return RequestHandle(req, self)
 
@@ -587,35 +621,39 @@ class ServingEngine:
         c = self.prefill_chunk
         chunks = [prompt[i:i + c] for i in range(0, len(prompt), c)]
         pos0, tok = 0, None
-        for i, chunk in enumerate(chunks):
-            arr = np.asarray(chunk, np.int32)[None]          # [1, c]
-            if self.cfg.family == "audio":
-                arr = np.broadcast_to(
-                    arr[:, None],
-                    (1, self.cfg.n_codebooks, len(chunk))).copy()
-            final = i == len(chunks) - 1
-            with self._mesh_scope():
-                out = self._prefill_fn(self.params, self.cache,
-                                       jnp.asarray(arr), slot, pos0,
-                                       pos0 + len(chunk), self.last_tokens,
-                                       self.key, final)
-            if final:
-                self.cache, self.last_tokens, tok, self.key = out
-            else:
-                self.cache = out
-            pos0 += len(chunk)
-            self.stats["prefill_dispatches"] += 1
-        self.stats["prefill_tokens"] += len(prompt)
-        self._pos_host[slot] = len(prompt)
-        req._first_tok = tok
-        req._start_tick = self._tick
-        req._n_gen = 1
-        req._n_dec = 0
-        if req.first_token_ns is None:
-            req.first_token_ns = self.clock_ns
-        self.stats["decode_tokens"] += 1
-        if self.sync_prefill:
-            tok.block_until_ready()
+        with self.spans.span("serve.prefill", req.rid) as attrs:
+            if attrs is not None:
+                attrs["chunks"] = [(i * c, len(ch))
+                                   for i, ch in enumerate(chunks)]
+            for i, chunk in enumerate(chunks):
+                arr = np.asarray(chunk, np.int32)[None]          # [1, c]
+                if self.cfg.family == "audio":
+                    arr = np.broadcast_to(
+                        arr[:, None],
+                        (1, self.cfg.n_codebooks, len(chunk))).copy()
+                final = i == len(chunks) - 1
+                with self._mesh_scope():
+                    out = self._prefill_fn(
+                        self.params, self.cache, jnp.asarray(arr), slot,
+                        pos0, pos0 + len(chunk), self.last_tokens, self.key,
+                        final)
+                if final:
+                    self.cache, self.last_tokens, tok, self.key = out
+                else:
+                    self.cache = out
+                pos0 += len(chunk)
+                self.stats["prefill_dispatches"] += 1
+            self.stats["prefill_tokens"] += len(prompt)
+            self._pos_host[slot] = len(prompt)
+            req._first_tok = tok
+            req._start_tick = self._tick
+            req._n_gen = 1
+            req._n_dec = 0
+            if req.first_token_ns is None:
+                req.first_token_ns = self.clock_ns
+            self.stats["decode_tokens"] += 1
+            if self.sync_prefill:
+                tok.block_until_ready()
 
     def _prefill_slot_legacy(self, req: Request, slot: int) -> None:
         """Pre-rewrite path: one decode_step dispatch per prompt token on a
@@ -718,23 +756,26 @@ class ServingEngine:
         (greedy-identical to a fresh prefill) rather than extending the
         previous generation.
         """
-        first = int(entry["first_token"])
-        kv = jax.tree_util.tree_map(jnp.asarray, entry["kv"])
-        self.cache["kv"] = jax.tree_util.tree_map(
-            lambda a, h: a.at[:, slot].set(h.astype(a.dtype)),
-            self.cache["kv"], kv)
-        self.cache["pos"] = self.cache["pos"].at[slot].set(
-            int(entry["pos"]))
-        self.last_tokens = self.last_tokens.at[slot].set(first)
-        self._pos_host[slot] = int(entry["pos"])
-        req.restored = True
-        req._first_tok = None
-        req._start_tick = self._tick
-        req.generated = req.generated + [first]
-        req._n_gen = 1
-        req._n_dec = 0
-        if req.first_token_ns is None:
-            req.first_token_ns = self.clock_ns
+        with self.spans.span("serve.restore", req.rid) as attrs:
+            if attrs is not None:
+                attrs["bytes"] = CxlTier.entry_bytes(entry)
+            first = int(entry["first_token"])
+            kv = jax.tree_util.tree_map(jnp.asarray, entry["kv"])
+            self.cache["kv"] = jax.tree_util.tree_map(
+                lambda a, h: a.at[:, slot].set(h.astype(a.dtype)),
+                self.cache["kv"], kv)
+            self.cache["pos"] = self.cache["pos"].at[slot].set(
+                int(entry["pos"]))
+            self.last_tokens = self.last_tokens.at[slot].set(first)
+            self._pos_host[slot] = int(entry["pos"])
+            req.restored = True
+            req._first_tok = None
+            req._start_tick = self._tick
+            req.generated = req.generated + [first]
+            req._n_gen = 1
+            req._n_dec = 0
+            if req.first_token_ns is None:
+                req.first_token_ns = self.clock_ns
 
     # -------------------------------------------------- preemption state
     def _capture_slot_kv(self, slot: int):
@@ -797,7 +838,11 @@ class ServingEngine:
     # ----------------------------------------------------------- advance
     def _advance(self) -> None:
         """One fused decode+sample dispatch; tokens stay on device."""
-        with self._mesh_scope():
+        with self.spans.span("serve.decode") as attrs, self._mesh_scope():
+            if attrs is not None:
+                attrs["live"] = [self._pos_host[s] + 1
+                                 for s, r in enumerate(self.slots)
+                                 if r is not None]
             self.cache, self.last_tokens, self.key = self._decode_fn(
                 self.params, self.cache, self.last_tokens, self.key)
         self.stats["steps"] += 1
@@ -872,23 +917,24 @@ class ServingEngine:
         flush to the host tier in the background. The only host transfers
         on the hot path happen here: the request's sampled tokens and its
         retiring pages."""
-        req = self.slots[slot]
-        req.done = True
-        req.state = sched.RETIRED
-        req.finish_ns = self.clock_ns
-        if not self.legacy:
-            self._materialize_tokens(req, slot)
-        kv_slot = self._capture_slot_kv(slot)
-        if kv_slot is not None and req.generated:
-            # snapshot the post-prefill state: pages + the prompt's first
-            # sampled token at pos=len(prompt). Pages beyond the prompt
-            # are masked by pos and overwritten as a restored slot decodes.
-            self.flusher.stage(req.rid, {
-                "kv": kv_slot, "pos": len(req.prompt),
-                "first_token": req.generated[0],
-                "prompt": tuple(req.prompt)})
-        self.finished.append(req)
-        self.slots[slot] = None
+        with self.spans.span("serve.retire", self.slots[slot].rid):
+            req = self.slots[slot]
+            req.done = True
+            req.state = sched.RETIRED
+            req.finish_ns = self.clock_ns
+            if not self.legacy:
+                self._materialize_tokens(req, slot)
+            kv_slot = self._capture_slot_kv(slot)
+            if kv_slot is not None and req.generated:
+                # snapshot the post-prefill state: pages + the prompt's first
+                # sampled token at pos=len(prompt). Pages beyond the prompt
+                # are masked by pos and overwritten as a restored slot decodes.
+                self.flusher.stage(req.rid, {
+                    "kv": kv_slot, "pos": len(req.prompt),
+                    "first_token": req.generated[0],
+                    "prompt": tuple(req.prompt)})
+            self.finished.append(req)
+            self.slots[slot] = None
 
     def _tok_tick(self, t: int) -> np.ndarray:
         """Materialize one tick's [n_slots] sampled tokens, memoized so
@@ -929,28 +975,31 @@ class ServingEngine:
             self.tier.free_entry(rid)
 
     def _store_sink(self, rid: int, entry) -> None:
-        if self.tier is not None:
-            # the background drain: page writes ride the deterministic-
-            # store path (GPU-speed completion, divert under congestion).
-            # In async mode the flush is a background op — the writer is
-            # held only for the issue-slot wait and the media work
-            # completes on the port cursors as simulated time passes.
-            nbytes = CxlTier.entry_bytes(entry)
-            if self.cxl_async:
-                handle = self.tier.write_entry_async(rid, nbytes)
-                self._async_writes.append(handle)
-                self.stats["tier_write_ns"] += handle.issue_wait_ns
-                self.scheduler._note_inflight_peak()
-            else:
-                self.stats["tier_write_ns"] += self.tier.write_entry(
-                    rid, nbytes)
-        kept = self.store.put(rid, entry)
-        # alias only entries that survived admission: budget pressure can
-        # evict an entry during its own put (oversized, or a re-staged rid
-        # growing past the budget), and its on_evict has already fired —
-        # indexing it afterwards would leak a dangling prompt alias
-        if kept and isinstance(entry, dict) and "prompt" in entry:
-            self._prompt_index[entry["prompt"]] = rid
+        with self.spans.span("serve.flush", rid) as attrs:
+            if attrs is not None:
+                attrs["bytes"] = CxlTier.entry_bytes(entry)
+            if self.tier is not None:
+                # the background drain: page writes ride the deterministic-
+                # store path (GPU-speed completion, divert under congestion).
+                # In async mode the flush is a background op — the writer is
+                # held only for the issue-slot wait and the media work
+                # completes on the port cursors as simulated time passes.
+                nbytes = CxlTier.entry_bytes(entry)
+                if self.cxl_async:
+                    handle = self.tier.write_entry_async(rid, nbytes)
+                    self._async_writes.append(handle)
+                    self.stats["tier_write_ns"] += handle.issue_wait_ns
+                    self.scheduler._note_inflight_peak()
+                else:
+                    self.stats["tier_write_ns"] += self.tier.write_entry(
+                        rid, nbytes)
+            kept = self.store.put(rid, entry)
+            # alias only entries that survived admission: budget pressure can
+            # evict an entry during its own put (oversized, or a re-staged rid
+            # growing past the budget), and its on_evict has already fired —
+            # indexing it afterwards would leak a dangling prompt alias
+            if kept and isinstance(entry, dict) and "prompt" in entry:
+                self._prompt_index[entry["prompt"]] = rid
 
     def _n_generated(self, req: Request) -> int:
         return len(req.generated) if self.legacy else req._n_gen
@@ -972,39 +1021,41 @@ class ServingEngine:
         the tick its completion lands. Only when *every* occupied slot
         is awaiting a fetch does the tick idle — that simulated time is
         exposed stall, accounted against the overlap ratio."""
-        self.scheduler.begin_tick()
-        for slot in range(self.n_slots):
-            if self.slots[slot] is not None:
-                self._check_done(slot)   # prefill/restore may already satisfy
-        active = any(s is not None for s in self.slots)
-        if not active and not self.scheduler.busy():
-            return
-        if not active:
-            # all occupied slots are RESTORING: the batch idles this tick
-            # while simulated time (below) brings the completions closer
-            self.scheduler.note_blocked_tick(self.tier_step_ns)
-        elif self.legacy:
-            sampled = self._advance_legacy()
-            for slot, tok in sampled.items():
-                req = self.slots[slot]
-                req.generated.append(tok)
-                self.stats["decode_tokens"] += 1
-                self._check_done(slot)
-        else:
-            self._advance()
+        with self.spans.span("serve.step"):
+            self.scheduler.begin_tick()
             for slot in range(self.n_slots):
                 if self.slots[slot] is not None:
+                    # prefill/restore may already satisfy
                     self._check_done(slot)
-        if not self.legacy:
-            self._prune_trace()
-        # QoS: occupancy = queue pressure; flushes gated by DevLoad
-        occ = len(self.flusher.pending) / max(self.n_slots * 2, 1)
-        dl = self.qos.classify(occupancy=min(occ, 1.0), service_ratio=1.0)
-        self.qos.update(dl)
-        self.stats["flushes"] += self.flusher.maybe_flush()
-        self._tier_tick()
-        self.stats["store_bytes"] = self.store.bytes
-        self.stats["store_evictions"] = self.store.evictions
+            active = any(s is not None for s in self.slots)
+            if not active and not self.scheduler.busy():
+                return
+            if not active:
+                # all occupied slots are RESTORING: the batch idles this tick
+                # while simulated time (below) brings the completions closer
+                self.scheduler.note_blocked_tick(self.tier_step_ns)
+            elif self.legacy:
+                sampled = self._advance_legacy()
+                for slot, tok in sampled.items():
+                    req = self.slots[slot]
+                    req.generated.append(tok)
+                    self.stats["decode_tokens"] += 1
+                    self._check_done(slot)
+            else:
+                self._advance()
+                for slot in range(self.n_slots):
+                    if self.slots[slot] is not None:
+                        self._check_done(slot)
+            if not self.legacy:
+                self._prune_trace()
+            # QoS: occupancy = queue pressure; flushes gated by DevLoad
+            occ = len(self.flusher.pending) / max(self.n_slots * 2, 1)
+            dl = self.qos.classify(occupancy=min(occ, 1.0), service_ratio=1.0)
+            self.qos.update(dl)
+            self.stats["flushes"] += self.flusher.maybe_flush()
+            self._tier_tick()
+            self.stats["store_bytes"] = self.store.bytes
+            self.stats["store_evictions"] = self.store.evictions
 
     def _tier_tick(self) -> None:
         """Advance simulated time one engine tick and surface tier +

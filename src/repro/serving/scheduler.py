@@ -57,7 +57,6 @@ slots and the jitted hot path. ``preempt_policy="none"`` with
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict
 
 from repro.core.tier import CxlTier
@@ -230,9 +229,10 @@ class RequestScheduler:
                 continue
             req = self._pop_next()
             req.slot = slot
-            t0 = time.perf_counter()
+            if req._queued_ns is not None:
+                eng.spans.add("serve.queue", req._queued_ns, req.rid)
+                req._queued_ns = None
             self._place(req, slot)
-            eng.stats["prefill_time_s"] += time.perf_counter() - t0
 
     def _place(self, req, slot: int) -> None:
         """Route one admitted request: swap-in, prefix restore or prefill."""

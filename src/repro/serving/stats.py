@@ -86,8 +86,7 @@ class EngineStats(_StatsMapping):
 
     The field list *is* the schema: ``serve_bench.SCHEMA_KEYS`` and the
     documented table in docs/ARCHITECTURE.md both derive from
-    :meth:`field_names`. All times are simulated nanoseconds unless the
-    suffix says otherwise (``prefill_time_s`` is wall seconds).
+    :meth:`field_names`. All times are simulated nanoseconds.
     """
 
     # hot-path counters
@@ -98,7 +97,6 @@ class EngineStats(_StatsMapping):
     prefill_dispatches: int = 0          # jitted prefill-chunk dispatches
     decode_dispatches: int = 0           # fused decode+sample dispatches
     prefix_hits: int = 0                 # admissions served via restore
-    prefill_time_s: float = 0.0          # wall time in prefill (admission)
     store_bytes: int = 0                 # HostPageStore LRU occupancy
     store_evictions: int = 0             # HostPageStore LRU evictions
     # CXL-tier accounting (all zero without a tier): simulated ns the
