@@ -266,8 +266,20 @@ class HostPageStore:
     entry during its own insert (a re-staged rid growing past the budget,
     or any oversized entry), and indexing such an entry would leak — the
     eviction callback for it has already fired by the time ``put``
-    returns. ``spans`` times the device-to-host copy of each insert
-    (``serve.flush.copy``) while it is on.
+    returns.
+
+    The device-to-host copy completes in the background (the
+    deterministic store's drain): ``put`` starts the copy of the entry's
+    device leaves and returns with the entry *pending*, its leaves still
+    device arrays; admission and accounting are settled at once, from
+    the same byte counts. At most one entry is pending: the next ``put``
+    first waits for its copy and swaps its leaves for the host arrays
+    (the finalize), as does :meth:`sync`. A pending entry that is
+    dropped, evicted or replaced is discarded with its device buffers.
+    ``flush_async`` counts the puts that returned pending,
+    ``flush_wait_ms`` the host time spent finalizing. ``spans`` times the
+    blocking part of each put's copy (``serve.flush.copy``: the previous
+    entry's finalize and the start of this one's copy) while it is on.
     """
 
     def __init__(self, budget_bytes: Optional[int] = None, on_evict=None,
@@ -279,6 +291,9 @@ class HostPageStore:
         self.spans = spans if spans is not None else SpanLog()
         self.bytes = 0
         self.evictions = 0
+        self.flush_async = 0
+        self.flush_wait_ms = 0.0
+        self._pending: Optional[Dict] = None    # entry whose copy is in flight
 
     # one canonical pytree-size helper for the whole page path: the tier
     # charges the same byte counts this budget is accounted in
@@ -289,22 +304,38 @@ class HostPageStore:
         if not isinstance(entry, dict) or "kv" not in entry:
             entry = {"kv": entry}      # bare-pytree compat (pre-entry API)
         entry = dict(entry)
-        # the captured pages wait on the decode that wrote them: block
-        # first, so that the copy span times the copy alone
-        jax.block_until_ready(entry["kv"])
+        leaves = [a for a in jax.tree_util.tree_leaves(entry["kv"])
+                  if isinstance(a, jax.Array)]
         with self.spans.span("serve.flush.copy") as attrs:
             if attrs is not None:
                 attrs["bytes"] = self._entry_bytes(entry["kv"])
-            entry["kv"] = jax.tree_util.tree_map(np.asarray, entry["kv"])
+            self.sync()
+            for a in leaves:
+                a.copy_to_host_async()
         if rid in self.pages:
-            old = self.pages.pop(rid)
-            self.bytes -= self._entry_bytes(old)
-            if self.on_evict is not None:
-                self.on_evict(rid, old, "replace")
+            self._remove(rid, "replace")
         self.pages[rid] = entry
         self.bytes += self._entry_bytes(entry)
         self._evict()
-        return rid in self.pages
+        if rid not in self.pages:
+            return False
+        if leaves:
+            self._pending = entry
+            self.flush_async += 1
+        return True
+
+    def sync(self) -> None:
+        """Finalize the pending entry, if any: wait for its copy to land
+        and hold its leaves as host arrays from then on."""
+        entry, self._pending = self._pending, None
+        if entry is not None:
+            t0 = time.perf_counter()
+            entry["kv"] = jax.tree_util.tree_map(np.asarray, entry["kv"])
+            self.flush_wait_ms += (time.perf_counter() - t0) * 1e3
+
+    def is_pending(self, entry) -> bool:
+        """Whether ``entry``'s device-to-host copy is still in flight."""
+        return entry is not None and entry is self._pending
 
     def get(self, rid: int):
         """Fetch ``rid``'s entry (refreshing LRU recency), else None."""
@@ -323,24 +354,26 @@ class HostPageStore:
         tier segments are released the same way); returns True iff the
         rid was present.
         """
-        old = self.pages.pop(rid, None)
-        if old is None:
+        if rid not in self.pages:
             return False
-        self.bytes -= self._entry_bytes(old)
-        self.evictions += 1
-        if self.on_evict is not None:
-            self.on_evict(rid, old, "evict")
+        self._remove(rid, "evict")
         return True
+
+    def _remove(self, rid: int, reason: str) -> None:
+        old = self.pages.pop(rid)
+        if old is self._pending:
+            self._pending = None
+        self.bytes -= self._entry_bytes(old)
+        if reason == "evict":
+            self.evictions += 1
+        if self.on_evict is not None:
+            self.on_evict(rid, old, reason)
 
     def _evict(self) -> None:
         if self.budget_bytes is None:
             return
         while self.bytes > self.budget_bytes and self.pages:
-            rid, old = self.pages.popitem(last=False)
-            self.bytes -= self._entry_bytes(old)
-            self.evictions += 1
-            if self.on_evict is not None:
-                self.on_evict(rid, old, "evict")
+            self._remove(next(iter(self.pages)), "evict")
 
 
 class ServingEngine:
@@ -759,6 +792,9 @@ class ServingEngine:
         with self.spans.span("serve.restore", req.rid) as attrs:
             if attrs is not None:
                 attrs["bytes"] = CxlTier.entry_bytes(entry)
+            if self.store.is_pending(entry):
+                # its leaves are still the device arrays: no transfer
+                self.stats["flush_pending_restores"] += 1
             first = int(entry["first_token"])
             kv = jax.tree_util.tree_map(jnp.asarray, entry["kv"])
             self.cache["kv"] = jax.tree_util.tree_map(
@@ -1054,8 +1090,7 @@ class ServingEngine:
             self.qos.update(dl)
             self.stats["flushes"] += self.flusher.maybe_flush()
             self._tier_tick()
-            self.stats["store_bytes"] = self.store.bytes
-            self.stats["store_evictions"] = self.store.evictions
+            self._store_stats()
 
     def _tier_tick(self) -> None:
         """Advance simulated time one engine tick and surface tier +
@@ -1191,15 +1226,23 @@ class ServingEngine:
         Whatever the horizon, outstanding async tier ops are drained
         before returning: pending flushes/swap writes complete on the
         simulated clock and in-flight restores land (their requests
-        settle into slots; they still need decode ticks to finish)."""
+        settle into slots; they still need decode ticks to finish); the
+        host store's last device-to-host copy lands too."""
         ticks = 0
         while (self.queue or any(s is not None for s in self.slots)
                or self.scheduler.busy()) and ticks < max_ticks:
             self.step()
             ticks += 1
         self.flusher.maybe_flush()
+        self.store.sync()
         self._drain_async()
         self._tier_tick()
-        self.stats["store_bytes"] = self.store.bytes
-        self.stats["store_evictions"] = self.store.evictions
+        self._store_stats()
         return self.finished
+
+    def _store_stats(self) -> None:
+        st, store = self.stats, self.store
+        st["store_bytes"] = store.bytes
+        st["store_evictions"] = store.evictions
+        st["flush_async"] = store.flush_async
+        st["flush_wait_ms"] = store.flush_wait_ms

@@ -21,9 +21,12 @@ The engine's spans, each at the boundary where its work happens:
 ``serve.decode``   the fused decode dispatch; ``live`` lists the live
                    context per live slot
 ``serve.retire``   one slot's retirement, with its token transfer
-``serve.flush``    one entry into the host store: the wait for its
-                   pages, the copy, the insert and evictions; ``bytes``
-``serve.flush.copy`` the device-to-host copy alone; ``bytes``
+``serve.flush``    one entry into the host store: the blocking part of
+                   its copy, the insert and evictions; ``bytes``
+``serve.flush.copy`` the blocking part of the device-to-host copy: the
+                   wait for the previous entry's copy to land and the
+                   start of this one's (it completes in the background);
+                   ``bytes`` of this entry
 ================== ====================================================
 """
 from __future__ import annotations

@@ -86,7 +86,8 @@ class EngineStats(_StatsMapping):
 
     The field list *is* the schema: ``serve_bench.SCHEMA_KEYS`` and the
     documented table in docs/ARCHITECTURE.md both derive from
-    :meth:`field_names`. All times are simulated nanoseconds.
+    :meth:`field_names`. All times are simulated nanoseconds, except
+    ``flush_wait_ms`` (host wall clock).
     """
 
     # hot-path counters
@@ -99,6 +100,13 @@ class EngineStats(_StatsMapping):
     prefix_hits: int = 0                 # admissions served via restore
     store_bytes: int = 0                 # HostPageStore LRU occupancy
     store_evictions: int = 0             # HostPageStore LRU evictions
+    # the host store's device-to-host copies, on the host's wall clock
+    # (not simulated): flushes whose put returned with the copy still in
+    # flight, ms spent waiting for copies to land, and restores served
+    # from an entry whose copy was in flight (its device arrays)
+    flush_async: int = 0
+    flush_wait_ms: float = 0.0
+    flush_pending_restores: int = 0
     # CXL-tier accounting (all zero without a tier): simulated ns the
     # restore path stalled on cold-tier fetches / the flusher held on EP
     # writes, the EP's SR hit rate, DS staging-stack fill, and flush

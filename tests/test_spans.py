@@ -134,6 +134,7 @@ def test_store_times_the_copy_alone():
     assert store.put(1, {"kv": kv})
     (name, t0, t1, parent, rid, attrs), = log.records()
     assert name == "serve.flush.copy" and attrs["bytes"] == 64
+    store.sync()
     assert isinstance(store.pages[1]["kv"]["k"], np.ndarray)
 
 
