@@ -30,7 +30,7 @@ class Window:
     handles: list                    # their engine handles, same order
     latencies_s: List[float]         # open loop: requests due in it
     tokens: int = 0                  # closed loop: output tokens made
-    counters: Optional[Dict[str, int]] = None
+    counters: Optional[Dict[str, float]] = None
     queue_depth: List[tuple] = dataclasses.field(default_factory=list)
 
 

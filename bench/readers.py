@@ -2,15 +2,25 @@
 
 Each reader takes the run's record (built in ``bench/run.py``) and
 returns one number, or None where the record holds nothing to read:
-then the metric is left out of the result line.
+then the metric is left out of the result line. A call's operations and
+bytes come from the module of the record's model family, under the
+record's ``root`` (the checkout that ran it; this one where the record
+does not say).
 """
 from __future__ import annotations
 
+import pathlib
 from typing import Dict, Optional
 
 import numpy as np
 
-from bench import costs
+from bench import costs, spec
+
+
+def family(record: Dict):
+    """The module of the record's model family (its costs)."""
+    root = pathlib.Path(record.get("root") or spec.ROOT)
+    return spec.family_module(root, record["model"]["family"])
 
 
 def percentile_ms(record: Dict, q: float) -> Optional[float]:
@@ -53,18 +63,18 @@ def decode_roofline(record: Dict) -> Optional[float]:
              if lo <= ts <= hi and live]
     if not lives:
         return None
-    m = record["model"]
-    least = np.mean([costs.least_time(*costs.decode_call(m, live), peaks)
+    m, fam = record["model"], family(record)
+    least = np.mean([costs.least_time(*fam.decode_call(m, live), peaks)
                      for live in lives])
     return 100.0 * least * p[0] / p[1]
 
 
 def window_flops(record: Dict) -> float:
     """Model flops of every prefill and decode call of the window."""
-    m = record["model"]
-    f = sum(costs.decode_call(m, live)[0]
+    m, fam = record["model"], family(record)
+    f = sum(fam.decode_call(m, live)[0]
             for _, live in record["decode_calls"])
-    f += sum(costs.prefill_call(m, pos0, n)
+    f += sum(fam.prefill_call(m, pos0, n)
              for _, pos0, n in record["prefill_calls"])
     return float(f)
 

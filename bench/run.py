@@ -37,7 +37,8 @@ for _p in (ROOT, ROOT / "src"):
     if str(_p) not in sys.path:
         sys.path.insert(0, str(_p))
 
-from bench import check, drive, spec, system, trace, traffic  # noqa: E402
+from bench import (check, drive, spans, spec, system, trace,  # noqa: E402
+                   traffic)
 from bench.peaks import peaks_for  # noqa: E402
 
 TRACE_SECONDS = 8.0            # profiled tail of the window (--trace 1)
@@ -100,7 +101,11 @@ def enable_cache() -> str:
 
 
 class Tracer:
-    """Profiles the last ``TRACE_SECONDS`` of the window (``--trace 1``)."""
+    """Profiles the last ``TRACE_SECONDS`` of the window (``--trace 1``).
+    From the window's start it keeps the ``Recorder``'s records and the
+    engine's span log (``bench/spans.py``), whose records cover the whole
+    window and whose ``serve.*`` spans the profile shows beside the
+    device ops."""
 
     def __init__(self, seconds: float, recorder):
         import jax
@@ -112,7 +117,9 @@ class Tracer:
         self._span = None
 
     def __call__(self, now: float) -> None:
-        self.recorder.on = True
+        if not self.recorder.on:
+            self.recorder.on = True
+            spans.start(self.recorder.engine)
         if self.dir is None and now >= self.start_at:
             self.dir = tempfile.mkdtemp(prefix="bench_trace_")
             self.jax.profiler.start_trace(self.dir)
@@ -121,17 +128,22 @@ class Tracer:
             self.t = [time.perf_counter(), None]
 
     def stop(self, n_devices: int):
+        """The trace's reduction (None where the profile never started)
+        and :func:`spans.reduce` of the engine's span log over it."""
         if self.dir is None:
-            return None
+            return None, spans.reduce(self.recorder.engine)
         self._span.__exit__(None, None, None)
         self.t[1] = time.perf_counter()
         self.jax.profiler.stop_trace()
         try:
-            red = trace.reduce_file(trace.find_xplane(self.dir), n_devices)
+            xplane = trace.find_xplane(self.dir)
+            red = trace.reduce_file(xplane, n_devices)
+            serve = spans.reduce(self.recorder.engine,
+                                 spans.reduce_file(xplane))
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
         red["host_t0"], red["host_t1"] = self.t
-        return red
+        return red, serve
 
 
 def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
@@ -163,7 +175,7 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
     plan = traffic.build(cell.traffic, seed, seconds, m["vocab_size"])
     phases = [("start", time.perf_counter() - t0)]
     with system.mesh_scope(config):
-        engine = system.build_engine(config, seed)
+        engine = system.build_engine(config, seed, root)
         phases.append(("engine", time.perf_counter() - t0))
         system.warm(engine, m["vocab_size"],
                     restores=bool(cell.traffic.get("restores")))
@@ -186,11 +198,14 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
         win = loop(engine, plan, seconds, traced=traced, on_time=tracer)
         in_window = clock.n - n0
         mem = system.peak_memory(cell.chips)
+        traced_red, serve_spans = tracer.stop(cell.chips) if tracer else \
+            (None, None)
         done = check.finished(win)
         admitted = sum(1 for h in win.handles
                        if h is not None and h.request.state != "QUEUED")
         record = {
-            "cell": cell.name, "loop": plan.loop, "model": m,
+            "cell": cell.name, "root": str(root), "loop": plan.loop,
+            "model": m,
             "n_slots": engine.n_slots, "window_s": win.seconds,
             "setup_s": setup_s, "latencies_s": win.latencies_s,
             "tokens": win.tokens, "counters": win.counters,
@@ -201,7 +216,7 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
             "prefill_calls": recorder.prefill if recorder else [],
             "programs": {"decode": system.DECODE_PROGRAM,
                          "prefill": system.PREFILL_PROGRAM},
-            "trace": tracer.stop(cell.chips) if tracer else None,
+            "trace": traced_red, "serve_spans": serve_spans,
             "queue_depth": win.queue_depth}
         win_depth = win.queue_depth
         engine = recorder = win = tracer = None
@@ -212,8 +227,8 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
         f"{record['requests']['submitted']} submitted, "
         f"{record['requests']['completed']} done, {n_lat} latency samples "
         f"({n_lat - math.ceil(0.9 * n_lat)} beyond p90), "
-        f"{record['tokens']} closed-loop tokens; counters "
-        f"{record['counters']}")
+        f"{record['tokens']} closed-loop tokens; counters not 0 "
+        f"{ {k: v for k, v in record['counters'].items() if v} }")
     log(f"[bench] programs made ready inside the window: {in_window}; "
         f"queue depth, first and second half: {_halves(win_depth)}")
     picks = check.sample(done, seed)

@@ -14,11 +14,12 @@ Two sources, each on its own clock:
   the host is inside ``serve.flush`` is summed apart.
 
 A program without the span log reduces to None, and its metrics read
-nothing. ``bench/run.py`` does not call this module yet: a traced run
-would :func:`start` the log when the ``Recorder`` turns on, keep
+nothing. A traced run of ``bench/run.py`` starts the log (:func:`start`)
+when the ``Recorder`` turns on, at the window's start, keeps
 :func:`reduce_file` of the window's ``.xplane.pb`` before the trace
-directory is deleted, and put :func:`reduce` into its record under
-``serve_spans``, where the readers at the end find it.
+directory is deleted, and puts :func:`reduce` into its record under
+``serve_spans``, where the readers at the end find it. Untraced runs
+leave the log off.
 """
 from __future__ import annotations
 
@@ -137,12 +138,14 @@ def reduce_planes(ops: List[Tuple[int, int]],
 
 def reduce_file(path: str) -> Optional[Dict]:
     """:func:`reduce_planes` of a recorded ``.xplane.pb``: the ops of
-    device 0, the host's ``serve.*`` spans, the window span."""
+    device 0, the host's ``serve.*`` spans, the window span. None where
+    the trace holds no device plane (a CPU run)."""
     from jax.profiler import ProfileData
-    ops, spans, win = [], [], []
+    ops, spans, win, device = [], [], [], False
     for plane in ProfileData.from_file(path).planes:
         m = trace.DEVICE_PLANE.match(plane.name)
         if m and int(m.group(1)) == 0:
+            device = True
             ops = [(s, e) for _, s, e in trace._events(plane, trace.OPS_LINE)]
         elif plane.name == trace.HOST_PLANE:
             for line in plane.lines:
@@ -153,6 +156,8 @@ def reduce_file(path: str) -> Optional[Dict]:
                         spans.append((s, e, ev.name))
                     elif ev.name == trace.WINDOW_SPAN:
                         win.append((s, e))
+    if not device:
+        return None
     window = (min(s for s, _ in win), max(e for _, e in win)) if win else None
     return reduce_planes(ops, spans, window)
 

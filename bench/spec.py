@@ -13,6 +13,22 @@ new cell needs only new files under ``bench/`` and new entries in
   :mod:`bench.traffic` turns into requests;
 * ``bench/metrics/<metric>.py``: a reader ``read(record)`` from the run's
   record to one number, or None where it finds nothing to read.
+
+A configuration of a model family the benchmark has not run before
+brings, besides its configuration file, two more files, and needs no
+edit to any file here:
+
+* ``bench/families/<family>.py``, found by ``model.family``:
+  ``program_params(seed, m)``, the program's parameter tree in bf16,
+  made on the device from the seed; ``decode_call(m, live)``, the
+  (flops, bytes) one decode call needs, ``live`` listing each live
+  slot's context; ``prefill_call(m, pos0, n)``, the flops of ``n``
+  prompt tokens after ``pos0`` cached ones;
+* ``bench/reference/<reference>.py``, named by the configuration's
+  ``reference``: ``gaps(seed, m, seqs, precision)`` as
+  :mod:`bench.reference.dense` has it, computed in float32 from leaves
+  remade through the family's module, with ``precision="fp8"`` the
+  control.
 """
 from __future__ import annotations
 
@@ -111,6 +127,18 @@ def metric_reader(root: pathlib.Path, name: str) -> Callable:
     path = root / "bench" / "metrics" / f"{name}.py"
     return _load_module(path, "bench_metric_" + name.replace(".", "_")
                         .replace("-", "_")).read
+
+
+def family_module(root: pathlib.Path, family: str):
+    """The weights and costs of a model family,
+    ``<root>/bench/families/<family>.py``. A family without one stops
+    here, naming the missing file: no other family's tree stands in."""
+    path = root / "bench" / "families" / f"{family}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"no module for the model family {family!r}: {path} is missing "
+            f"(it gives program_params, decode_call and prefill_call)")
+    return _load_module(path, "bench_family_" + family.replace("-", "_"))
 
 
 def reference_module(root: pathlib.Path, name: str):

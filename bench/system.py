@@ -2,9 +2,11 @@
 
 The engine is built as ``repro.launch.serve.build_engine`` builds one
 (the same ``RunConfig`` and ``ServeConfig`` path), with the benchmark's
-own seeded weights. Set-up warms exactly the programs a cell's traffic
-uses; :class:`Recorder` adds the benchmark's spans and per-call records
-around the engine's calls into its layers, in traced runs only.
+own seeded weights, made by the module of the configuration's model
+family (``bench/families/<family>.py``). Set-up warms exactly the
+programs a cell's traffic uses; :class:`Recorder` adds the benchmark's
+spans and per-call records around the engine's calls into its layers,
+in traced runs only.
 """
 from __future__ import annotations
 
@@ -15,14 +17,12 @@ from typing import Dict, List, Optional
 import jax
 import numpy as np
 
-from bench import spec, weights
+from bench import spec
 
 # jit module names of the engine's two hot programs, as the trace shows
 # them ("jit_<function name>"); the program gives them no stable name yet
 DECODE_PROGRAM = "_decode_sample"
 PREFILL_PROGRAM = "_prefill_chunk_body"
-COUNTERS = ("steps", "prefill_tokens", "decode_tokens", "flushes",
-            "prefill_dispatches", "decode_dispatches", "prefix_hits")
 WARM_RID = 10 ** 9          # request ids of set-up's own requests
 FILL_RID = 2 * 10 ** 9
 
@@ -33,15 +33,17 @@ def mesh_scope(config: Dict):
     return host_mesh_scope(spec.serve_config(config, 0))
 
 
-def build_engine(config: Dict, seed: int):
-    """The engine of a configuration file with seeded weights; call it
-    inside :func:`mesh_scope`."""
+def build_engine(config: Dict, seed: int, root=spec.ROOT):
+    """The engine of a configuration file with seeded weights made by
+    its family's module under ``root``; call it inside
+    :func:`mesh_scope`."""
     from repro.configs.base import SHAPES, MeshConfig, RunConfig
     from repro.serving.engine import ServingEngine
 
     cfg = spec.model_config(config)
     rc = RunConfig(model=cfg, shape=SHAPES["decode_32k"], mesh=MeshConfig())
-    params = weights.program_params(seed, config["model"])
+    m = config["model"]
+    params = spec.family_module(root, m["family"]).program_params(seed, m)
     return ServingEngine(params, cfg, rc,
                          config=spec.serve_config(config, seed))
 
@@ -98,9 +100,10 @@ def fill_store(engine, prompts) -> None:
     jax.block_until_ready(engine.cache)
 
 
-def counters(engine) -> Dict[str, int]:
-    """The engine's hot-path counters now."""
-    return {k: int(engine.stats[k]) for k in COUNTERS}
+def counters(engine) -> Dict[str, float]:
+    """Every numeric field of the engine's stats now."""
+    return {k: v for k, v in engine.stats.items()
+            if isinstance(v, (int, float)) and not isinstance(v, bool)}
 
 
 def entry_bytes(engine) -> int:

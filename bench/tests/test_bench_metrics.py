@@ -108,6 +108,26 @@ def test_prefill_chunk_arithmetic():
     assert _read("prefix_hit_share", r) == 0.0
 
 
+@pytest.mark.parametrize("cell", ["qwen3-1.7b.doc-reuse",
+                                  "qwen3-1.7b.chat-unique"])
+def test_span_metrics_arithmetic(cell):
+    r = _load(DATA / f"record-{cell}-1.json")
+    by, t = r["serve_spans"]["by_name"], r["serve_spans"]["trace"]
+    for metric, span in (("queue_wait_ms.serve", "serve.queue"),
+                         ("flush_ms.serve", "serve.flush"),
+                         ("restore_ms.serve", "serve.restore")):
+        want = 1e3 * by[span]["seconds"] / by[span]["n"] \
+            if span in by else None
+        assert _read(metric, r) == (pytest.approx(want) if want else None)
+    assert by["serve.flush"]["n"] == r["counters"]["flushes"]
+    assert by.get("serve.restore", {"n": 0})["n"] == \
+        r["counters"]["prefix_hits"]
+    assert t["window_s"] == pytest.approx(r["trace"]["window_s"])
+    assert _read("flush_idle_share.serve", r) == pytest.approx(
+        100 * t["flush_idle_s"] / t["window_s"])
+    assert 0 < t["flush_idle_s"] <= t["idle_s"]
+
+
 def test_decode_cost_by_hand():
     m = json.loads((ROOT / "bench/configs/qwen3-1.7b.json").read_text())[
         "model"]

@@ -13,7 +13,7 @@ from bench.tests.test_bench_harness import TINY
 
 DATA = pathlib.Path(__file__).parent / "data"
 MS = 1_000_000
-READERS = {  # the five per-layer metrics the records hold
+READERS = {  # the readers of bench/spans.py (flush_copy_gbps is no metric)
     "queue_wait_ms.serve": lambda r: spans.mean_ms(r, "serve.queue"),
     "flush_ms.serve": lambda r: spans.mean_ms(r, "serve.flush"),
     "flush_copy_gbps.serve": spans.copy_gbps,

@@ -2,6 +2,7 @@
 same requests, every seed the same lengths in another order, and the
 lengths and arrivals follow the file's stated distributions."""
 import collections
+import hashlib
 import json
 import pathlib
 
@@ -112,3 +113,74 @@ def test_schedule_seed_fixes_the_order(path):
     assert [(len(r.prompt), r.max_new, r.due_s) for r in c.requests] != \
         [(len(r.prompt), r.max_new, r.due_s) for r in a.requests]
 
+
+# sha256 (first 16 hex digits) of every request and the store fill, as the
+# generator made them before it read "arrival": the Poisson files must
+# still give these plans bit for bit
+POISSON_PLANS = {("doc-reuse", 7): "7c0c6291427e807a",
+                 ("doc-reuse", 2 ** 33 + 5): "f16c2e50d40830f2",
+                 ("chat-unique", 7): "232ad595cf15ea3e",
+                 ("chat-unique", 2 ** 33 + 5): "480e1f999073cb3b",
+                 ("reason-batch", 7): "e5a2309aae701986",
+                 ("reason-batch", 2 ** 33 + 5): "4e8e7942fba9c1e3"}
+
+
+def _digest(plan):
+    h = hashlib.sha256()
+    for r in plan.requests:
+        h.update(repr((r.rid, r.prompt, r.max_new, r.due_s, r.doc)).encode())
+    h.update(repr(plan.store_fill).encode())
+    return h.hexdigest()[:16]
+
+
+@pytest.mark.parametrize("name,seed", sorted(POISSON_PLANS))
+def test_poisson_files_give_the_same_plan_as_before(name, seed):
+    t = _load(ROOT / "bench" / "traffic" / f"{name}.json")
+    assert t.get("arrival", "poisson") == "poisson"
+    assert _digest(traffic.build(t, seed, 51.0, VOCAB)) == \
+        POISSON_PLANS[name, seed]
+    if t["loop"] == "open":        # "poisson" is also the default
+        t.pop("arrival")
+        assert _digest(traffic.build(t, seed, 51.0, VOCAB)) == \
+            POISSON_PLANS[name, seed]
+
+
+@pytest.mark.parametrize("rate,seconds,size", [(0.64, 51.0, 8),
+                                               (2.0, 30.0, 4),
+                                               (0.1, 5.0, 8)])
+def test_bursts_are_whole_and_due_together(rate, seconds, size):
+    t = dict(_load(ROOT / "bench" / "traffic" / "doc-reuse-bursty.json"),
+             rate_rps=rate, burst_size=size)
+    plan = traffic.build(t, 2 ** 33 + 3, seconds, VOCAB)
+    bursts = max(1, round(rate * seconds / size))
+    dues = [r.due_s for r in plan.requests]
+    assert len(dues) == bursts * size
+    assert dues == sorted(dues)
+    starts = sorted(set(dues))
+    assert len(starts) == bursts
+    assert all(dues.count(s) == size for s in starts)
+    gaps = np.diff([0.0] + starts)
+    want = -np.log1p(-(np.arange(bursts) + 0.5) / bursts) / (rate / size)
+    np.testing.assert_allclose(sorted(gaps), want, rtol=1e-12)
+    # lengths and documents are drawn as a Poisson file of as many
+    # requests draws them
+    poisson = traffic.build(dict(t, arrival="poisson",
+                                 rate_rps=bursts * size / seconds),
+                            2 ** 33 + 3, seconds, VOCAB)
+    assert [(r.prompt, r.max_new, r.doc) for r in plan.requests] == \
+        [(r.prompt, r.max_new, r.doc) for r in poisson.requests]
+
+
+def test_bursty_file_sends_four_bursts_of_eight_in_one_order():
+    t = _load(ROOT / "bench" / "traffic" / "doc-reuse-bursty.json")
+    assert (t["arrival"], t["burst_size"], t["schedule_seed"]) == \
+        ("burst", 8, 1)
+    plans = [traffic.build(t, s, 51.0, VOCAB) for s in (3, 2 ** 31 + 11)]
+    order = [[(r.due_s, r.doc, r.max_new) for r in p.requests]
+             for p in plans]
+    assert order[0] == order[1]
+    assert len(order[0]) == 32 and len({d for d, _, _ in order[0]}) == 4
+    other = traffic.build(dict(t, schedule_seed=2), 3, 51.0, VOCAB)
+    assert [(r.due_s, r.doc, r.max_new) for r in other.requests] != order[0]
+    with pytest.raises(ValueError, match="arrival"):
+        traffic.build(dict(t, arrival="uniform"), 3, 51.0, VOCAB)

@@ -3,9 +3,12 @@ file of parameters in, a seeded list of requests out.
 
 A file holds:
 
-* ``loop``: ``"open"`` (requests due on a fixed schedule, ``rate_rps``,
-  Poisson gaps) or ``"closed"`` (``clients`` callers, each sending its
+* ``loop``: ``"open"`` (requests due on a fixed schedule at a mean of
+  ``rate_rps``) or ``"closed"`` (``clients`` callers, each sending its
   next request when the last one is done);
+* ``arrival`` (open loop): ``"poisson"`` (the default), one request per
+  Poisson gap, or ``"burst"``, ``burst_size`` requests due at the same
+  second, the bursts Poisson at ``rate_rps / burst_size``;
 * ``prompts``: ``"catalog"`` (a catalog of ``catalog.n_docs`` documents
   asked for with zipf(``catalog.zipf_s``) popularity; set-up stores the
   ``catalog.prefill_store`` most popular) or ``"unique"``;
@@ -124,6 +127,21 @@ def poisson_gaps(rate: float, n: int, rng: np.random.Generator):
     return gaps
 
 
+def _arrivals(traffic: Dict, seconds: float, rng: np.random.Generator):
+    """An open loop's request count and due seconds, in order."""
+    rate = float(traffic["rate_rps"])
+    arrival = traffic.get("arrival", "poisson")
+    if arrival == "poisson":
+        n = max(1, int(round(rate * seconds)))
+        return n, np.cumsum(poisson_gaps(rate, n, rng))
+    if arrival == "burst":
+        size = int(traffic["burst_size"])
+        bursts = max(1, int(round(rate * seconds / size)))
+        starts = np.cumsum(poisson_gaps(rate / size, bursts, rng))
+        return bursts * size, np.repeat(starts, size)
+    raise ValueError(f"unknown arrival {arrival!r}")
+
+
 def _tokens(rng: np.random.Generator, n: int, vocab: int):
     return tuple(int(t) for t in rng.integers(1, vocab, size=n))
 
@@ -138,9 +156,7 @@ def build(traffic: Dict, seed: int, seconds: float, vocab: int) -> Plan:
     loop = traffic["loop"]
     block = int(traffic.get("stratify_block", 0))
     if loop == "open":
-        n = max(1, int(round(float(traffic["rate_rps"]) * seconds)))
-        dues = np.cumsum(poisson_gaps(float(traffic["rate_rps"]), n,
-                                      rng["gaps"]))
+        n, dues = _arrivals(traffic, seconds, rng["gaps"])
     elif loop == "closed":
         n = int(traffic["clients"]) * int(traffic.get("requests_per_client",
                                                       8))
