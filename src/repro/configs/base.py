@@ -39,8 +39,12 @@ class ModelConfig:
     # MLP details
     activation: str = "swiglu"  # swiglu | geglu | gelu (plain 2-matrix MLP)
 
-    # MoE
+    # MoE: the router scores all ``n_experts``; this model holds the
+    # ``n_experts_held`` of them from ``first_expert`` on (0: all), one
+    # chip's share under expert parallelism
     n_experts: int = 0
+    n_experts_held: int = 0
+    first_expert: int = 0
     top_k: int = 0
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.001
@@ -78,6 +82,10 @@ class ModelConfig:
         return self.n_kv_heads * self.head_dim
 
     @property
+    def n_held_experts(self) -> int:
+        return self.n_experts_held or self.n_experts
+
+    @property
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
 
@@ -97,7 +105,7 @@ class ModelConfig:
         attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
         n_glu = 3 if self.activation in ("swiglu", "geglu") else 2
         if self.family == "moe":
-            mlp = self.n_experts * n_glu * d * self.d_ff
+            mlp = self.n_held_experts * n_glu * d * self.d_ff
             per_layer = attn + mlp + d * self.n_experts  # + router
         elif self.family in ("dense", "vlm", "audio"):
             mlp = n_glu * d * self.d_ff

@@ -1,5 +1,6 @@
 """qwen3-moe-235b-a22b [moe] — 94L d_model=4096 64H (GQA kv=4) expert d_ff=1536
-vocab=151936, MoE 128 experts top-8, qk_norm. [hf:Qwen/Qwen3-30B-A3B family; hf]
+vocab=151936, MoE 128 experts top-8, qk_norm.
+[https://huggingface.co/Qwen/Qwen3-235B-A22B/blob/main/config.json]
 """
 from repro.configs.base import ModelConfig
 

@@ -39,7 +39,9 @@ def smoke(arch_id: str) -> ModelConfig:
         head_dim=16, d_ff=128 if cfg.d_ff else 0, vocab_size=256,
     )
     if cfg.family == "moe":
-        updates.update(n_experts=8, top_k=2, d_ff=64)
+        # a held share smaller than the whole, off the first expert
+        updates.update(n_experts=8, n_experts_held=4, first_expert=2,
+                       top_k=2, d_ff=64)
     if cfg.family == "hybrid":
         updates.update(shared_block_period=2, ssm_state=16, ssm_head_dim=16,
                        n_layers=4, n_heads=4, n_kv_heads=4, head_dim=16)

@@ -9,7 +9,7 @@ combine, and (b) is the same layout the serving engine's tiered pager uses.
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -376,8 +376,13 @@ def _paged_block_decode(block_fn, layer, cfg, x, pos, kv, rc):
 
 
 def decode_step(params: Dict, cfg: ModelConfig, rc: RunConfig, tokens,
-                cache: Dict, param_specs: Dict) -> Tuple[jnp.ndarray, Dict]:
-    """One-token decode. tokens: [B, 1] (audio: [B, K, 1])."""
+                cache: Dict, param_specs: Dict, *, routing: bool = False):
+    """One-token decode. tokens: [B, 1] (audio: [B, K, 1]).
+
+    Returns (logits, new cache), and with ``routing`` a third value: the
+    MoE layers' routing counts summed over layers (``moe.moe_apply_held``),
+    None for families without experts.
+    """
     pos = cache["pos"]                     # [B] per-slot positions
     x = embed_apply(params["embed"], cfg, tokens)
     b = x.shape[0]
@@ -388,17 +393,19 @@ def decode_step(params: Dict, cfg: ModelConfig, rc: RunConfig, tokens,
     fam = cfg.family
     key = stacked_key(cfg)
     new_cache = dict(cache)
+    counts = None
 
     if fam in ("dense", "moe", "audio"):
-        block_fn = (moe.moe_block_decode_paged if fam == "moe"
-                    else transformer.block_decode_paged)
-
         def body(x, layer, kv):
-            x, kv2 = _paged_block_decode(block_fn, layer, cfg, x, pos, kv,
-                                         rc)
-            return x, kv2
+            if fam == "moe":
+                x, kv2, c = _paged_block_decode(moe.moe_block_decode_paged,
+                                                layer, cfg, x, pos, kv, rc)
+                return x, (kv2, c)
+            x, kv2 = _paged_block_decode(transformer.block_decode_paged,
+                                         layer, cfg, x, pos, kv, rc)
+            return x, (kv2, None)
 
-        x, kv_out = sr.stream_layers(
+        x, (kv_out, counts) = sr.stream_layers(
             body, x, params[key], param_specs[key], n_layers=cfg.n_layers,
             prefetch_depth=rc.sr_prefetch_depth,
             granularity=rc.sr_granularity, mode="infer", remat=False,
@@ -417,7 +424,14 @@ def decode_step(params: Dict, cfg: ModelConfig, rc: RunConfig, tokens,
     x = rmsnorm(params["ln_f"], x, cfg.norm_eps)
     logits = unembed_apply(params["embed"], cfg, x)
     new_cache["pos"] = pos + 1
+    if routing:
+        return logits, new_cache, _layer_sum(counts)
     return logits, new_cache
+
+
+def _layer_sum(counts):
+    """Per-layer routing counts [L, ...] summed over layers (None stays)."""
+    return None if counts is None else counts.sum(axis=0)
 
 
 def _decode_vlm(params, cfg, rc, x, pos, cache, param_specs):
@@ -545,7 +559,8 @@ def _decode_ssm(params, cfg, rc, x, pos, cache, param_specs):
 def _block_prefill_cached(layer: Dict, cfg: ModelConfig, rc: RunConfig,
                           x: jnp.ndarray, positions: jnp.ndarray,
                           pos: jnp.ndarray, kv: Dict, *, moe_mlp: bool):
-    """One block over a C-token chunk, writing K/V into the paged cache.
+    """One block over a C-token chunk, writing K/V into the paged cache;
+    returns (x, kv, the MoE layer's routing counts or None).
 
     x: [B, C, d]; pos: [B] per-row start positions; kv: {"k","v"} each
     [B, n_pages, page, Hkv, D]. The chunk K/V are written in-graph at
@@ -576,8 +591,9 @@ def _block_prefill_cached(layer: Dict, cfg: ModelConfig, rc: RunConfig,
         q, kf, vf, pos, logit_softcap=cfg.attn_logit_softcap)
     x = x + o.reshape(bsz, -1, cfg.q_dim) @ layer["attn"]["wo"]
     h = rmsnorm(layer["ln_mlp"], x, cfg.norm_eps)
+    counts = None
     if moe_mlp:
-        y, _ = moe.moe_apply_ep(layer["moe"], cfg, h)
+        y, counts = moe.moe_apply_held(layer["moe"], cfg, h)
         x = x + y
     else:
         x = x + mlp_apply(layer["mlp"], cfg, h)
@@ -586,26 +602,29 @@ def _block_prefill_cached(layer: Dict, cfg: ModelConfig, rc: RunConfig,
                                                kv["k_scale"])
         vq, vs = kv_quant_lib.requantize_pages(vf.reshape(vd.shape),
                                                kv["v_scale"])
-        return x, {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
-    return x, {"k": kf.reshape(kv["k"].shape), "v": vf.reshape(kv["v"].shape)}
+        return x, {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}, counts
+    return x, {"k": kf.reshape(kv["k"].shape),
+               "v": vf.reshape(kv["v"].shape)}, counts
 
 
 def prefill_step_cached(params: Dict, cfg: ModelConfig, rc: RunConfig,
-                        tokens, cache: Dict,
-                        param_specs: Dict) -> Tuple[jnp.ndarray, Dict]:
+                        tokens, cache: Dict, param_specs: Dict, *,
+                        routing: bool = False):
     """Chunked multi-token prefill that writes the paged KV cache in-graph.
 
     tokens: [B, C] int32 (audio: [B, K, C]). Every batch row ingests its C
     tokens starting at its own ``cache["pos"]``; returns (logits for all C
-    chunk positions, updated cache with pos advanced by C). Attention
+    chunk positions, updated cache with pos advanced by C), and with
+    ``routing`` the routing counts as ``decode_step`` gives them. Attention
     families (dense/moe/audio) run one parallel chunk forward per layer;
     recurrent families (vlm/hybrid/ssm) fall back to an in-graph
     ``lax.scan`` over ``decode_step`` — still a single dispatch per chunk.
     """
     fam = cfg.family
     if fam not in ("dense", "moe", "audio"):
-        return _prefill_scan_cached(params, cfg, rc, tokens, cache,
-                                    param_specs)
+        out = _prefill_scan_cached(params, cfg, rc, tokens, cache,
+                                   param_specs)
+        return out + (None,) if routing else out
     pos = cache["pos"]
     x = embed_apply(params["embed"], cfg, tokens)
     b, c = x.shape[0], x.shape[1]
@@ -615,11 +634,12 @@ def prefill_step_cached(params: Dict, cfg: ModelConfig, rc: RunConfig,
         x = x + sinusoidal_positions(positions, cfg.d_model).astype(x.dtype)
 
     def body(x, layer, kv):
-        return _block_prefill_cached(layer, cfg, rc, x, positions, pos, kv,
-                                     moe_mlp=(fam == "moe"))
+        x, kv2, c = _block_prefill_cached(layer, cfg, rc, x, positions, pos,
+                                          kv, moe_mlp=(fam == "moe"))
+        return x, (kv2, c)
 
     key = stacked_key(cfg)
-    x, kv_out = sr.stream_layers(
+    x, (kv_out, counts) = sr.stream_layers(
         body, x, params[key], param_specs[key], n_layers=cfg.n_layers,
         prefetch_depth=rc.sr_prefetch_depth, granularity=rc.sr_granularity,
         mode="infer", remat=False, stacked_extras=cache["kv"],
@@ -629,6 +649,8 @@ def prefill_step_cached(params: Dict, cfg: ModelConfig, rc: RunConfig,
     new_cache["pos"] = pos + c
     x = rmsnorm(params["ln_f"], x, cfg.norm_eps)
     logits = unembed_apply(params["embed"], cfg, x)
+    if routing:
+        return logits, new_cache, _layer_sum(counts)
     return logits, new_cache
 
 

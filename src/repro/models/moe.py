@@ -1,22 +1,32 @@
-"""Mixture-of-Experts layer: top-k routing with expert parallelism.
+"""Mixture-of-Experts layer: top-k routing, expert parallelism, held shares.
 
-Two dispatch paths:
+The router scores all ``cfg.n_experts``; a layer holds the
+``cfg.n_held_experts`` of them from ``cfg.first_expert`` on (all of them
+by default; one chip's share under expert parallelism otherwise) and adds
+what its own experts give. What the absent experts would add is left out.
 
-* ``moe_apply_ep`` (train/prefill) — shard_map expert parallelism. Tokens
-  are (data x model)-sharded; experts are model-sharded. Each rank routes
-  its local tokens, packs fixed-capacity per-destination send buffers, and
-  one ``all_to_all`` over the model axis moves tokens to the rank owning
-  their expert (the return trip mirrors it). All scatters are local-shaped,
-  so GSPMD never sees a partitioned scatter — the naive global scatter
+Two forms:
+
+* ``moe_apply_ep`` (training) — shard_map expert parallelism. Tokens are
+  (data x model)-sharded; experts are model-sharded. Each rank routes its
+  local tokens, packs fixed-capacity per-destination send buffers, and one
+  ``all_to_all`` over the model axis moves tokens to the rank owning their
+  expert (the return trip mirrors it). All scatters are local-shaped, so
+  GSPMD never sees a partitioned scatter — the naive global scatter
   (``moe_apply``) makes XLA replicate [T, ...] buffers (observed: 191 GB
-  temp/device on granite train_4k vs ~1 GB with this path).
+  temp/device on granite train_4k vs ~1 GB with this path). Experts take
+  at most ``capacity_factor * t * k / e`` tokens each and drop the rest;
+  ``moe_apply`` (single-device semantics) is its oracle.
 
-* ``moe_apply_ep_decode`` (single-token decode) — tokens are small, so
-  they stay replicated across the model axis; each rank computes only its
-  local experts' contribution and a psum over the model axis combines.
-
-``moe_apply`` (pure, single-device semantics) remains the oracle for
-tests.
+* ``moe_apply_held`` (serving: prefill and decode) — dropless. A token
+  picks an expert at most once, so every held expert takes a row for every
+  token (a capacity of ``t`` rows, which no routing overflows) and each
+  token adds the gated outputs of the held experts it routed to. On a mesh
+  tokens stay replicated over the model axis, rank ``r`` holds ``e_loc``
+  experts from ``first_expert + r * e_loc`` on, and a psum combines; on one
+  chip the layer runs with the config's range and no exchange. It also
+  returns the layer's routing counts: token-routings that landed on held
+  experts, and held experts that took at least one token.
 """
 from __future__ import annotations
 
@@ -32,29 +42,35 @@ from repro.models.layers import (dense_init, pdtype, rmsnorm, rmsnorm_init)
 
 
 def moe_init(key, cfg: ModelConfig) -> Dict:
-    d, ff, e, dt = cfg.d_model, cfg.d_ff, cfg.n_experts, pdtype(cfg)
+    d, ff, dt = cfg.d_model, cfg.d_ff, pdtype(cfg)
+    e, eh = cfg.n_experts, cfg.n_held_experts
     ks = jax.random.split(key, 4)
     return {"router": dense_init(ks[0], d, e, jnp.float32, scale=0.02),
-            "e_gate": (jax.random.normal(ks[1], (e, d, ff)) * 0.02
+            "e_gate": (jax.random.normal(ks[1], (eh, d, ff)) * 0.02
                        ).astype(dt),
-            "e_up": (jax.random.normal(ks[2], (e, d, ff)) * 0.02).astype(dt),
-            "e_down": (jax.random.normal(ks[3], (e, ff, d)) * 0.02
+            "e_up": (jax.random.normal(ks[2], (eh, d, ff)) * 0.02).astype(dt),
+            "e_down": (jax.random.normal(ks[3], (eh, ff, d)) * 0.02
                        ).astype(dt)}
+
+
+def route(router: jnp.ndarray, xt: jnp.ndarray, k: int):
+    """Softmax over every expert in float32, its top ``k``, renormalised:
+    (gates [t, k], experts [t, k], probabilities [t, e])."""
+    probs = jax.nn.softmax(xt.astype(jnp.float32) @ router, axis=-1)
+    gate_w, gate_i = jax.lax.top_k(probs, k)
+    gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
+    return gate_w, gate_i, probs
 
 
 def moe_apply(params: Dict, cfg: ModelConfig,
               x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """x: [B, S, d] -> (y, aux_loss). Capacity C = cf * T * k / E."""
+    """x: [B, S, d] -> (y, aux_loss). Capacity C = cf * T * k / E per
+    held expert; routings to experts not held here add nothing."""
     b, s, d = x.shape
     t = b * s
-    e, k = cfg.n_experts, cfg.top_k
+    e, k, eh = cfg.n_experts, cfg.top_k, cfg.n_held_experts
     xt = x.reshape(t, d)
-
-    # --- routing (float32 for a stable softmax) -------------------------
-    logits = xt.astype(jnp.float32) @ params["router"]          # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_w, gate_i = jax.lax.top_k(probs, k)                    # [T, k]
-    gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
+    gate_w, gate_i, probs = route(params["router"], xt, k)      # [T, k]
 
     # load-balance auxiliary loss (Switch-style)
     me = probs.mean(axis=0)                                     # [E]
@@ -66,23 +82,26 @@ def moe_apply(params: Dict, cfg: ModelConfig,
     capacity = min(capacity, t)
 
     # --- positions in expert (slot-major priority: k=0 first) -----------
-    flat_e = gate_i.T.reshape(-1)                               # [k*T]
-    onehot = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)         # [k*T, E]
+    flat_e = gate_i.T.reshape(-1) - cfg.first_expert           # [k*T]
+    held = (flat_e >= 0) & (flat_e < eh)
+    flat_e = jnp.where(held, flat_e, 0)
+    onehot = jax.nn.one_hot(flat_e, eh, dtype=jnp.int32) \
+        * held[:, None].astype(jnp.int32)                       # [k*T, Eh]
     pos = jnp.cumsum(onehot, axis=0) - onehot                   # pre-count
     pos = jnp.take_along_axis(pos, flat_e[:, None], axis=1)[:, 0]
-    keep = pos < capacity
+    keep = held & (pos < capacity)
     slot_w = gate_w.T.reshape(-1) * keep                        # [k*T]
 
-    # --- scatter dispatch: [E, C, d] -------------------------------------
+    # --- scatter dispatch: [Eh, C, d] ------------------------------------
     safe_pos = jnp.where(keep, pos, capacity - 1)
     src = jnp.tile(xt, (k, 1))
-    buf = jnp.zeros((e, capacity, d), xt.dtype)
+    buf = jnp.zeros((eh, capacity, d), xt.dtype)
     buf = buf.at[flat_e, safe_pos].add(jnp.where(keep[:, None], src, 0))
 
     # --- expert compute (E over the "model" axis) ------------------------
     h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buf, params["e_gate"])) \
         * jnp.einsum("ecd,edf->ecf", buf, params["e_up"])
-    y_e = jnp.einsum("ecf,efd->ecd", h, params["e_down"])       # [E, C, d]
+    y_e = jnp.einsum("ecf,efd->ecd", h, params["e_down"])       # [Eh, C, d]
 
     # --- gather combine (gate applied at combine: y = sum_i g_i e_i(x)) ---
     vals = y_e[flat_e, safe_pos]                                # [k*T, d]
@@ -115,7 +134,7 @@ def moe_apply_ep(params: Dict, cfg: ModelConfig, x: jnp.ndarray,
     e, k, d = cfg.n_experts, cfg.top_k, cfg.d_model
     nm = _mesh_axis_size("model")
     b, s, _ = x.shape
-    if nm == 1 or e % nm or s % nm:
+    if nm == 1 or e % nm or s % nm or cfg.n_held_experts != e:
         return moe_apply(params, cfg, x)
     e_loc = e // nm
     cf = cfg.capacity_factor
@@ -130,10 +149,7 @@ def moe_apply_ep(params: Dict, cfg: ModelConfig, x: jnp.ndarray,
         xt = xl.reshape(t, d)
 
         # ---- routing --------------------------------------------------
-        logits = xt.astype(jnp.float32) @ wr                    # [t, E]
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate_w, gate_i = jax.lax.top_k(probs, k)                # [t, k]
-        gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
+        gate_w, gate_i, probs = route(wr, xt, k)                # [t, k]
 
         # load-balance aux (global via pmean over every token shard)
         all_axes = ((dp_axes,) if isinstance(dp_axes, str)
@@ -210,59 +226,55 @@ def moe_apply_ep(params: Dict, cfg: ModelConfig, x: jnp.ndarray,
             params["e_down"])
 
 
-def moe_apply_ep_decode(params: Dict, cfg: ModelConfig, x: jnp.ndarray,
-                        dp_axes="data") -> jnp.ndarray:
-    """Expert-parallel MoE for single-token decode.
+def _held_part(xt, wr, wg, wu, wd, first, k: int):
+    """What experts [first, first + e) give each token of ``xt`` [t, d],
+    dropless, with (routings landing here, experts touched) as int32."""
+    gate_w, gate_i, _ = route(wr, xt, k)
+    onehot = jax.nn.one_hot(gate_i - first, wg.shape[0],
+                            dtype=jnp.float32)                  # [t, k, e]
+    gates = (gate_w[..., None] * onehot).sum(axis=1).T          # [e, t]
+    routed = onehot.sum(axis=1).T > 0
+    with jax.named_scope("moe.experts"):
+        h = jax.nn.silu(jnp.einsum("td,edf->etf", xt, wg)) \
+            * jnp.einsum("td,edf->etf", xt, wu)
+        y_e = jnp.einsum("etf,efd->etd", h, wd)                 # [e, t, d]
+        y = (gates[..., None] * y_e.astype(jnp.float32)).sum(axis=0)
+    counts = jnp.stack([routed.sum(), routed.any(axis=1).sum()])
+    return y.astype(xt.dtype), counts.astype(jnp.int32)
 
-    Tokens are few: keep them replicated over the model axis, let each
-    rank compute only its local experts' gated contributions, and psum
-    over the model axis. No all_to_all, no drops.
+
+def moe_apply_held(params: Dict, cfg: ModelConfig, x: jnp.ndarray,
+                   dp_axes="data") -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Dropless MoE over the held experts (prefill and decode).
+
+    x: [B, S, d] -> (y, counts int32[2]: token-routings that landed on
+    held experts, held experts with at least one token). On a mesh each
+    data shard's batch counts its own touches, as each chip's would.
     """
-    e, k, d = cfg.n_experts, cfg.top_k, cfg.d_model
+    eh, k, d = cfg.n_held_experts, cfg.top_k, cfg.d_model
     nm = _mesh_axis_size("model")
-    if nm == 1 or e % nm:
-        return moe_apply(params, cfg, x)[0]
-    e_loc = e // nm
+    weights = (params["router"], params["e_gate"], params["e_up"],
+               params["e_down"])
+    if nm == 1 or eh % nm:
+        y, counts = _held_part(x.reshape(-1, d), *weights, cfg.first_expert,
+                               k)
+        return y.reshape(x.shape), counts
+    e_loc = eh // nm
+    axes = ((dp_axes,) if isinstance(dp_axes, str) else tuple(dp_axes)) \
+        + ("model",)
     x_spec = P(dp_axes, None, None)
     ep_spec = P("model", None, None)
 
     def local(xl, wr, wg, wu, wd):
-        bl, sl, _ = xl.shape
-        t = bl * sl
-        xt = xl.reshape(t, d)
-        rank = jax.lax.axis_index("model")
-        logits = xt.astype(jnp.float32) @ wr
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate_w, gate_i = jax.lax.top_k(probs, k)
-        gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
-
-        flat_e = gate_i.T.reshape(-1)                           # [k*t]
-        flat_g = gate_w.T.reshape(-1)
-        tok_of_slot = jnp.tile(jnp.arange(t, dtype=jnp.int32), (k,))
-        local_e = flat_e - rank * e_loc
-        mine = (local_e >= 0) & (local_e < e_loc)
-        local_e = jnp.where(mine, local_e, 0)
-
-        cap = t * k                      # no drops at decode
-        slot = jnp.arange(k * t, dtype=jnp.int32)
-        buf = jnp.zeros((e_loc, cap, d), xt.dtype).at[local_e, slot].add(
-            jnp.where(mine[:, None], jnp.take(xt, tok_of_slot, axis=0), 0))
-        h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buf, wg)) \
-            * jnp.einsum("ecd,edf->ecf", buf, wu)
-        y_e = jnp.einsum("ecf,efd->ecd", h, wd)
-        vals = y_e[local_e, slot]                               # [k*t, d]
-        vals = jnp.where(mine[:, None], vals, 0) \
-            * flat_g[:, None].astype(y_e.dtype)
-        y_t = vals.reshape(k, t, d).sum(axis=0)
-        y_t = jax.lax.psum(y_t, "model")
-        return y_t.reshape(bl, sl, d).astype(xl.dtype)
+        first = cfg.first_expert + jax.lax.axis_index("model") * e_loc
+        y, counts = _held_part(xl.reshape(-1, d), wr, wg, wu, wd, first, k)
+        return (jax.lax.psum(y, "model").reshape(xl.shape),
+                jax.lax.psum(counts, axes))
 
     return jax.shard_map(
         local,
         in_specs=(x_spec, P(None, None), ep_spec, ep_spec, ep_spec),
-        out_specs=x_spec)(
-            x, params["router"], params["e_gate"], params["e_up"],
-            params["e_down"])
+        out_specs=(x_spec, P()))(x, *weights)
 
 
 def moe_block_init(key, cfg: ModelConfig) -> Dict:
@@ -294,7 +306,8 @@ def moe_block_decode_paged(params: Dict, cfg: ModelConfig, x: jnp.ndarray,
                            page_axes, fuse_qkv: bool = True,
                            kv_block: int = 2048):
     """Single-token decode against a page-sharded cache (see
-    transformer.block_decode_paged)."""
+    transformer.block_decode_paged); also returns the MoE layer's routing
+    counts."""
     h = rmsnorm(params["ln_attn"], x, cfg.norm_eps)
     positions = jnp.broadcast_to(
         jnp.asarray(pos, jnp.int32).reshape(-1, 1), (x.shape[0], 1))
@@ -314,26 +327,7 @@ def moe_block_decode_paged(params: Dict, cfg: ModelConfig, x: jnp.ndarray,
         kv_out = {"k": k_pages, "v": v_pages}
     x = x + o.reshape(x.shape[0], 1, cfg.q_dim) @ params["attn"]["wo"]
     h = rmsnorm(params["ln_mlp"], x, cfg.norm_eps)
-    y = moe_apply_ep_decode(params["moe"], cfg, h,
-                            dp_axes=batch_axes or "data")
-    return x + y, kv_out
+    y, counts = moe_apply_held(params["moe"], cfg, h,
+                               dp_axes=batch_axes or "data")
+    return x + y, kv_out, counts
 
-
-def moe_block_decode(params: Dict, cfg: ModelConfig, x: jnp.ndarray,
-                     pos: jnp.ndarray, kv_cache, *, fuse_qkv: bool = True,
-                     kv_block: int = 2048):
-    k_cache, v_cache = kv_cache
-    h = rmsnorm(params["ln_attn"], x, cfg.norm_eps)
-    positions = jnp.broadcast_to(pos, (x.shape[0], 1)).astype(jnp.int32)
-    q, kk, v = attn.qkv_project(params["attn"], cfg, h, positions,
-                                fuse_qkv=fuse_qkv)
-    k_cache = jax.lax.dynamic_update_slice(
-        k_cache, kk.astype(k_cache.dtype), (0, pos, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(
-        v_cache, v.astype(v_cache.dtype), (0, pos, 0, 0))
-    o = attn.decode_attention(q, k_cache, v_cache, kv_len=pos + 1,
-                              kv_block=kv_block)
-    x = x + o.reshape(x.shape[0], 1, cfg.q_dim) @ params["attn"]["wo"]
-    h = rmsnorm(params["ln_mlp"], x, cfg.norm_eps)
-    y, _ = moe_apply(params["moe"], cfg, h)
-    return x + y, (k_cache, v_cache)

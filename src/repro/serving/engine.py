@@ -506,6 +506,11 @@ class ServingEngine:
         self._tick = 0                      # decode ticks executed
         self._trace: Dict[int, jax.Array] = {}      # tick -> [n_slots] toks
         self._trace_np: Dict[int, np.ndarray] = {}  # memoized transfers
+        # MoE routing counts the programs made since the last retirement,
+        # summed on the device: (token-routings on held experts, held
+        # experts touched in decode, decode calls); read at retirement
+        self._routing = (jnp.zeros((3,), jnp.int32) if cfg.family == "moe"
+                         else None)
         # jitted hot-path entry points (traced lazily on first use). The
         # batch cache is donated: nothing on the host ever re-reads an old
         # cache, and aliasing in/out buffers saves a full cache copy per
@@ -550,15 +555,19 @@ class ServingEngine:
                 (self.n_slots, self.cfg.n_codebooks, 1))
         else:
             toks = last_tokens[:, None]
-        logits, cache = M.decode_step(params, self.cfg, self.hot_rc, toks,
-                                      cache, self.pspecs)
+        logits, cache, counts = M.decode_step(
+            params, self.cfg, self.hot_rc, toks, cache, self.pspecs,
+            routing=True)
         row = M.last_token_logits(logits)
         if self.temperature > 0:
             key, sub = jax.random.split(key)
             nxt = M.sample_tokens(row, sub, self.temperature)
         else:
             nxt = M.sample_tokens(row, None, 0.0)
-        return cache, nxt, key
+        if counts is None:
+            return cache, nxt, key
+        return cache, nxt, key, jnp.concatenate(
+            [counts, jnp.ones((1,), jnp.int32)])
 
     def _prefill_chunk_body(self, params, cache, tokens, slot, pos0,
                             new_pos, last_tokens, key, sample):
@@ -572,23 +581,26 @@ class ServingEngine:
         static) samples the last-position token on device — one PRNG
         split per request, so sampled streams do not depend on the chunk
         size. Other slots never observe the prefill (continuous-batching
-        isolation).
+        isolation). A model with experts adds the chunk's routing counts
+        as the last output of either form.
         """
         baxes = self._batch_axes()
         cache1 = jax.tree_util.tree_map(
             lambda a, ax: jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=ax),
             cache, baxes)
         cache1["pos"] = jnp.full((1,), pos0, jnp.int32)
-        logits, cache1 = M.prefill_step_cached(params, self.cfg,
-                                               self.hot_rc, tokens, cache1,
-                                               self.pspecs)
+        logits, cache1, counts = M.prefill_step_cached(
+            params, self.cfg, self.hot_rc, tokens, cache1, self.pspecs,
+            routing=True)
+        routing = () if counts is None else (
+            jnp.concatenate([counts[:1], jnp.zeros((2,), jnp.int32)]),)
         cache1["pos"] = jnp.full((1,), new_pos, jnp.int32)
         cache = jax.tree_util.tree_map(
             lambda a, a1, ax: jax.lax.dynamic_update_slice_in_dim(
                 a, a1.astype(a.dtype), slot, axis=ax),
             cache, cache1, baxes)
         if not sample:
-            return cache
+            return (cache, *routing)
         row = M.last_token_logits(logits)            # [1, V]
         if self.temperature > 0:
             key, sub = jax.random.split(key)
@@ -596,7 +608,7 @@ class ServingEngine:
         else:
             tok = M.sample_tokens(row, None, 0.0)[0]
         last_tokens = last_tokens.at[slot].set(tok)
-        return cache, last_tokens, tok, key
+        return (cache, last_tokens, tok, key, *routing)
 
     # ------------------------------------------------------------ admit
     def submit(self, req: Request, *,
@@ -671,9 +683,10 @@ class ServingEngine:
                         pos0, pos0 + len(chunk), self.last_tokens, self.key,
                         final)
                 if final:
-                    self.cache, self.last_tokens, tok, self.key = out
+                    self.cache, self.last_tokens, tok, self.key, *routing = out
                 else:
-                    self.cache = out
+                    self.cache, *routing = out
+                self._add_routing(routing)
                 pos0 += len(chunk)
                 self.stats["prefill_dispatches"] += 1
             self.stats["prefill_tokens"] += len(prompt)
@@ -879,8 +892,10 @@ class ServingEngine:
                 attrs["live"] = [self._pos_host[s] + 1
                                  for s, r in enumerate(self.slots)
                                  if r is not None]
-            self.cache, self.last_tokens, self.key = self._decode_fn(
-                self.params, self.cache, self.last_tokens, self.key)
+            self.cache, self.last_tokens, self.key, *routing = \
+                self._decode_fn(self.params, self.cache, self.last_tokens,
+                                self.key)
+            self._add_routing(routing)
         self.stats["steps"] += 1
         self.stats["decode_dispatches"] += 1
         self._trace[self._tick] = self.last_tokens
@@ -960,6 +975,7 @@ class ServingEngine:
             req.finish_ns = self.clock_ns
             if not self.legacy:
                 self._materialize_tokens(req, slot)
+                self._read_routing()
             kv_slot = self._capture_slot_kv(slot)
             if kv_slot is not None and req.generated:
                 # snapshot the post-prefill state: pages + the prompt's first
@@ -971,6 +987,24 @@ class ServingEngine:
                     "prompt": tuple(req.prompt)})
             self.finished.append(req)
             self.slots[slot] = None
+
+    def _add_routing(self, routing) -> None:
+        """Sum a program's routing counts (its outputs after the usual
+        ones: none without experts) on the device, with no transfer."""
+        for counts in routing:
+            self._routing = self._routing + counts
+
+    def _read_routing(self) -> None:
+        """Fold the device's routing counts into the stats. Called at
+        retirement, whose token transfer has already waited for the
+        programs that made them, so this adds no wait of its own."""
+        if self._routing is None:
+            return
+        routed, touched, calls = (int(v) for v in np.asarray(self._routing))
+        self._routing = jnp.zeros_like(self._routing)
+        self.stats["moe_local_routings"] += routed
+        self.stats["moe_expert_touches"] += touched
+        self.stats["moe_decode_calls_counted"] += calls
 
     def _tok_tick(self, t: int) -> np.ndarray:
         """Materialize one tick's [n_slots] sampled tokens, memoized so

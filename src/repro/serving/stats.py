@@ -107,6 +107,14 @@ class EngineStats(_StatsMapping):
     flush_async: int = 0
     flush_wait_ms: float = 0.0
     flush_pending_restores: int = 0
+    # MoE routing, counted on the device by the prefill and decode
+    # programs and read at each retirement (so they trail the dispatch
+    # counters): token-routings that landed on held experts (prefill and
+    # decode), held experts that took at least one token, summed over
+    # layers and decode calls, and the decode calls those touches cover
+    moe_local_routings: int = 0
+    moe_expert_touches: int = 0
+    moe_decode_calls_counted: int = 0
     # CXL-tier accounting (all zero without a tier): simulated ns the
     # restore path stalled on cold-tier fetches / the flusher held on EP
     # writes, the EP's SR hit rate, DS staging-stack fill, and flush

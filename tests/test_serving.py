@@ -371,3 +371,41 @@ def test_engine_store_stats_surface(mesh_ctx):
     assert eng.stats["store_evictions"] == eng.store.evictions
     assert eng.store.bytes <= budget
     assert eng.store.evictions >= 1
+
+
+def test_moe_routing_counters_match_the_programs(mesh_ctx):
+    """The engine's routing counters are the counts its prefill and
+    decode programs make, summed on the device and read at retirement:
+    replayed here through the model's own steps on the served tokens."""
+    eng = _make("granite-moe-1b-a400m", n_slots=1, max_seq=32,
+                prefill_chunk=4)
+    cfg = eng.cfg
+    assert cfg.n_held_experts < cfg.n_experts
+    prompt = PROMPT[:10]
+    eng.submit(Request(rid=0, prompt=prompt, max_new_tokens=6))
+    out = eng.run(max_ticks=50)[0].generated
+
+    cache = M.cache_init(cfg, eng.hot_rc, 1, max_seq=32)
+    routed = touched = calls = 0
+    for i in range(0, len(prompt), 4):
+        _, cache, c = M.prefill_step_cached(
+            eng.params, cfg, eng.hot_rc,
+            jnp.asarray([prompt[i:i + 4]], jnp.int32), cache, eng.pspecs,
+            routing=True)
+        routed += int(c[0])
+    for t in out[:-1]:
+        _, cache, c = M.decode_step(eng.params, cfg, eng.hot_rc,
+                                    jnp.full((1, 1), t, jnp.int32), cache,
+                                    eng.pspecs, routing=True)
+        routed, touched, calls = (routed + int(c[0]), touched + int(c[1]),
+                                  calls + 1)
+    st = eng.stats
+    assert calls == st["decode_dispatches"] == 5
+    assert (st["moe_local_routings"], st["moe_expert_touches"],
+            st["moe_decode_calls_counted"]) == (routed, touched, calls)
+    assert 0 < touched <= calls * cfg.n_layers * cfg.top_k
+    # a dense model counts nothing
+    dense = _make(n_slots=1, max_seq=32)
+    dense.submit(Request(rid=0, prompt=prompt, max_new_tokens=3))
+    dense.run(max_ticks=20)
+    assert dense.stats["moe_local_routings"] == 0

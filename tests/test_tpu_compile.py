@@ -109,3 +109,38 @@ def test_qwen3_decode_step_fits_one_chip(one_chip):
     assert mem.temp_size_in_bytes < cache_bytes + GiB, mem
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < HBM_PER_CHIP, mem
+
+
+def test_qwen3_moe_share_programs_fit_one_chip(one_chip):
+    """The engine's decode tick and prefill chunk of qwen3-moe-235b-a22b
+    at one chip's share (16 of 94 layers, 8 of 128 experts) and 8 slots x
+    8192 tokens: 10.97 GiB of arguments, and the decode tick's temp is the
+    second cache copy (2 GiB) and the expert layer's buffers."""
+    from repro.serving.engine import ServingEngine
+    cfg = dataclasses.replace(registry.get("qwen3-moe-235b-a22b"),
+                              n_layers=16, n_experts_held=8)
+    rc = RunConfig(model=cfg, shape=SHAPES["decode_32k"], mesh=MeshConfig())
+    pshape = jax.eval_shape(lambda: M.init_model(jax.random.PRNGKey(0), cfg))
+    eng = ServingEngine(pshape, cfg, rc, n_slots=8, max_seq=8192,
+                        prefill_chunk=128)
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(eng.cache))
+    i32 = jnp.zeros((), jnp.int32)
+    toks = jnp.zeros((1, 128), jnp.int32)
+
+    def on(tree):
+        return _on(one_chip, jax.eval_shape(lambda: tree))
+
+    with jax.set_mesh(one_chip):
+        decode = jax.jit(eng._decode_sample, donate_argnums=(1,)).lower(
+            on(eng.params), on(eng.cache), on(eng.last_tokens),
+            on(eng.key)).compile()
+        prefill = jax.jit(eng._prefill_chunk_body, donate_argnums=(1,),
+                          static_argnums=(8,)).lower(
+            on(eng.params), on(eng.cache), on(toks), on(i32), on(i32),
+            on(i32), on(eng.last_tokens), on(eng.key), True).compile()
+    for compiled in (decode, prefill):
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < cache_bytes + GiB, mem
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            < HBM_PER_CHIP, mem
