@@ -22,7 +22,8 @@ overlap opportunities.
 
 Body contract: ``body(x, layer_params, extra_slice) -> (y, out_slice)`` where
 ``extra_slice``/``out_slice`` come from/stack into a leading layer axis
-(e.g. per-layer KV cache in/out). Use ``None`` when unused.
+(e.g. the layer's index in, the new tokens' K/V out). Use ``None`` when
+unused.
 """
 from __future__ import annotations
 

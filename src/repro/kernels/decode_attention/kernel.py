@@ -1,10 +1,10 @@
 """Pallas TPU paged flash-decode — single-token attention over KV pages.
 
-This is the per-device kernel behind the distributed paged decode
-(models.attention.paged_decode_attention): each device holds a page-
-sharded slice of the KV cache (its "endpoint" in the paper's terms) and
-scans its local pages with an online softmax; the cross-device combine is
-a tiny psum outside the kernel.
+This is the per-device kernel of the distributed paged decode (whose jnp
+form is models.attention.cached_attention's shard_map path): each device
+holds a page-sharded slice of the KV cache (its "endpoint" in the paper's
+terms) and scans its local pages with an online softmax; the cross-device
+combine is a tiny psum outside the kernel.
 
 Grid: (batch, kv_head, pages) with the page axis innermost; accumulator
 state in VMEM scratch; `kv_len` rides in scalar-prefetch memory — the

@@ -362,17 +362,65 @@ def cache_specs(cfg: ModelConfig, rc: RunConfig, batch: int) -> Dict:
     return jax.tree_util.tree_map_with_path(spec_for, cache)
 
 
+def cache_batch_axes(cfg: ModelConfig, rc: RunConfig) -> Dict:
+    """Each cache leaf's batch axis (found by differencing two batch
+    sizes)."""
+    a = cache_init(cfg, rc, 2, max_seq=rc.kv_page_size, as_shape=True)
+    b = cache_init(cfg, rc, 3, max_seq=rc.kv_page_size, as_shape=True)
+    return jax.tree_util.tree_map(
+        lambda x, y: next(i for i, (p, q) in enumerate(zip(x.shape, y.shape))
+                          if p != q), a, b)
+
+
 # ---------------------------------------------------------------------------
 # decode (serve_step body)
 # ---------------------------------------------------------------------------
 
 
-def _paged_block_decode(block_fn, layer, cfg, x, pos, kv, rc):
-    """One paged-attention decode block; the cache stays page-sharded (the
-    distributed write + combine happen inside paged_decode_attention)."""
+def _paged_block_decode(block_fn, layer, cfg, x, pos, kv, l, rc, row0=0):
+    """One paged-attention block against layer ``l`` of the stacked cache
+    ``kv``, read in place; the cache stays page-sharded (any cross-rank
+    combine happens inside attention.cached_attention)."""
     batch_axes, page_axes = decode_axes(rc, x.shape[0])
-    return block_fn(layer, cfg, x, pos, kv, batch_axes=batch_axes,
-                    page_axes=page_axes, fuse_qkv=rc.fuse_qkv)
+    return block_fn(layer, cfg, x, pos, kv, l, row0=row0,
+                    batch_axes=batch_axes, page_axes=page_axes,
+                    fuse_qkv=rc.fuse_qkv)
+
+
+def _block_decode_layer(block_fn, layer, cfg, x, pos, kv, rc):
+    """One block against a single layer's cache ``kv``, written in the
+    same call: the recurrent families' per-layer cache route."""
+    stacked = {name: a[None] for name, a in kv.items()}
+    x, (k, v) = _paged_block_decode(block_fn, layer, cfg, x, pos, stacked, 0,
+                                    rc)
+    out = attn_lib.write_kv(stacked, k[None], v[None], pos)
+    return x, {name: a[0] for name, a in out.items()}
+
+
+def _layers_cached(params, cfg, rc, x, pos, kv, param_specs, row0=0):
+    """The attention families' layer scan over C new tokens per row.
+
+    Each layer reads its K/V from the stacked cache ``kv`` in place (rows
+    ``row0`` on); the scan carries no cache and emits only the new
+    tokens' K/V. Returns (x, ((k, v) each [L, B, C, Hkv, D], the MoE
+    layers' routing counts [L, ...] or None)); attention.write_kv stores
+    the new K/V in one scatter after the scan.
+    """
+    block_fn = (moe.moe_block_decode_paged if cfg.family == "moe"
+                else transformer.block_decode_paged)
+
+    def body(x, layer, l):
+        out = _paged_block_decode(block_fn, layer, cfg, x, pos, kv, l, rc,
+                                  row0)
+        return out[0], (out[1], out[2] if len(out) > 2 else None)
+
+    key = stacked_key(cfg)
+    return sr.stream_layers(
+        body, x, params[key], param_specs[key], n_layers=cfg.n_layers,
+        prefetch_depth=rc.sr_prefetch_depth, granularity=rc.sr_granularity,
+        mode="infer", remat=False,
+        stacked_extras=jnp.arange(cfg.n_layers, dtype=jnp.int32),
+        unroll=rc.scan_unroll)
 
 
 def decode_step(params: Dict, cfg: ModelConfig, rc: RunConfig, tokens,
@@ -391,26 +439,13 @@ def decode_step(params: Dict, cfg: ModelConfig, rc: RunConfig, tokens,
         x = x + sinusoidal_positions(ppos, cfg.d_model).astype(x.dtype)
 
     fam = cfg.family
-    key = stacked_key(cfg)
     new_cache = dict(cache)
     counts = None
 
     if fam in ("dense", "moe", "audio"):
-        def body(x, layer, kv):
-            if fam == "moe":
-                x, kv2, c = _paged_block_decode(moe.moe_block_decode_paged,
-                                                layer, cfg, x, pos, kv, rc)
-                return x, (kv2, c)
-            x, kv2 = _paged_block_decode(transformer.block_decode_paged,
-                                         layer, cfg, x, pos, kv, rc)
-            return x, (kv2, None)
-
-        x, (kv_out, counts) = sr.stream_layers(
-            body, x, params[key], param_specs[key], n_layers=cfg.n_layers,
-            prefetch_depth=rc.sr_prefetch_depth,
-            granularity=rc.sr_granularity, mode="infer", remat=False,
-            stacked_extras=cache["kv"], unroll=rc.scan_unroll)
-        new_cache["kv"] = kv_out
+        x, (kv_new, counts) = _layers_cached(params, cfg, rc, x, pos,
+                                             cache["kv"], param_specs)
+        new_cache["kv"] = attn_lib.write_kv(cache["kv"], *kv_new, pos)
     elif fam == "vlm":
         x, new_cache = _decode_vlm(params, cfg, rc, x, pos, cache,
                                    param_specs)
@@ -447,8 +482,8 @@ def _decode_vlm(params, cfg, rc, x, pos, cache, param_specs):
             blk = jax.tree_util.tree_map(lambda a: a[i],
                                          group["self_blocks"])
             kv_i = jax.tree_util.tree_map(lambda a: a[i], kv)
-            x2, kv2 = _paged_block_decode(transformer.block_decode_paged, blk, cfg,
-                                          x, pos, kv_i, rc)
+            x2, kv2 = _block_decode_layer(transformer.block_decode_paged, blk,
+                                          cfg, x, pos, kv_i, rc)
             x = x2
             kv_new.append(kv2)
         # cross layer: reuse cached vision K/V, single-query attention
@@ -499,8 +534,8 @@ def _decode_hybrid(params, cfg, rc, x, pos, cache, param_specs):
         # shared attention block (single-token)
         sp = params["shared"]
         z = jnp.concatenate([x, emb], axis=-1) @ sp["in_map"]
-        z, kv2 = _paged_block_decode(transformer.block_decode_paged, sp["block"],
-                                     cfg, z, pos, kv, rc)
+        z, kv2 = _block_decode_layer(transformer.block_decode_paged,
+                                     sp["block"], cfg, z, pos, kv, rc)
         x = x + z @ sp["out_map"]
         return x, (kv2, jnp.stack(h_new), jnp.stack(conv_new))
 
@@ -556,97 +591,43 @@ def _decode_ssm(params, cfg, rc, x, pos, cache, param_specs):
 # ---------------------------------------------------------------------------
 
 
-def _block_prefill_cached(layer: Dict, cfg: ModelConfig, rc: RunConfig,
-                          x: jnp.ndarray, positions: jnp.ndarray,
-                          pos: jnp.ndarray, kv: Dict, *, moe_mlp: bool):
-    """One block over a C-token chunk, writing K/V into the paged cache;
-    returns (x, kv, the MoE layer's routing counts or None).
-
-    x: [B, C, d]; pos: [B] per-row start positions; kv: {"k","v"} each
-    [B, n_pages, page, Hkv, D]. The chunk K/V are written in-graph at
-    [pos, pos+C) (dynamic_update_slice on the flattened page view) before
-    the attention, so the chunk attends to prior context + its own causal
-    prefix through one multi-query flash-decode.
-    """
-    h = rmsnorm(layer["ln_attn"], x, cfg.norm_eps)
-    q, k, v = attn_lib.qkv_project(layer["attn"], cfg, h, positions,
-                                   fuse_qkv=rc.fuse_qkv)
-    bsz, n_pages, page = kv["k"].shape[0], kv["k"].shape[1], kv["k"].shape[2]
-    smax = n_pages * page
-    quant = "k_scale" in kv
-    kd = (kv_quant_lib.dequantize_pages(kv["k"], kv["k_scale"]) if quant
-          else kv["k"])
-    vd = (kv_quant_lib.dequantize_pages(kv["v"], kv["v_scale"]) if quant
-          else kv["v"])
-    kf = kd.reshape(bsz, smax, cfg.n_kv_heads, cfg.head_dim)
-    vf = vd.reshape(bsz, smax, cfg.n_kv_heads, cfg.head_dim)
-
-    def write(buf, new, p):
-        return jax.lax.dynamic_update_slice(buf, new.astype(buf.dtype),
-                                            (p, 0, 0))
-
-    kf = jax.vmap(write)(kf, k, pos)
-    vf = jax.vmap(write)(vf, v, pos)
-    o = attn_lib.chunk_prefill_attention(
-        q, kf, vf, pos, logit_softcap=cfg.attn_logit_softcap)
-    x = x + o.reshape(bsz, -1, cfg.q_dim) @ layer["attn"]["wo"]
-    h = rmsnorm(layer["ln_mlp"], x, cfg.norm_eps)
-    counts = None
-    if moe_mlp:
-        y, counts = moe.moe_apply_held(layer["moe"], cfg, h)
-        x = x + y
-    else:
-        x = x + mlp_apply(layer["mlp"], cfg, h)
-    if quant:
-        kq, ks = kv_quant_lib.requantize_pages(kf.reshape(kd.shape),
-                                               kv["k_scale"])
-        vq, vs = kv_quant_lib.requantize_pages(vf.reshape(vd.shape),
-                                               kv["v_scale"])
-        return x, {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}, counts
-    return x, {"k": kf.reshape(kv["k"].shape),
-               "v": vf.reshape(kv["v"].shape)}, counts
-
-
 def prefill_step_cached(params: Dict, cfg: ModelConfig, rc: RunConfig,
                         tokens, cache: Dict, param_specs: Dict, *,
-                        routing: bool = False):
+                        routing: bool = False, slot=0):
     """Chunked multi-token prefill that writes the paged KV cache in-graph.
 
-    tokens: [B, C] int32 (audio: [B, K, C]). Every batch row ingests its C
-    tokens starting at its own ``cache["pos"]``; returns (logits for all C
-    chunk positions, updated cache with pos advanced by C), and with
-    ``routing`` the routing counts as ``decode_step`` gives them. Attention
-    families (dense/moe/audio) run one parallel chunk forward per layer;
-    recurrent families (vlm/hybrid/ssm) fall back to an in-graph
-    ``lax.scan`` over ``decode_step`` — still a single dispatch per chunk.
+    tokens: [B, C] int32 (audio: [B, K, C]). Row b ingests its C tokens
+    into cache row ``slot + b`` (``slot`` may be traced; 0 with a
+    full-batch chunk), starting at that row's ``cache["pos"]``; returns
+    (logits for all C chunk positions, the cache with those rows' pos
+    advanced by C), and with ``routing`` the routing counts as
+    ``decode_step`` gives them. Attention families (dense/moe/audio) run
+    one parallel chunk forward per layer, reading each layer's K/V in
+    place, and write the chunk's K/V after the layer scan; recurrent
+    families (vlm/hybrid/ssm) fall back to an in-graph ``lax.scan`` over
+    ``decode_step`` on the rows' slice -- still a single dispatch per
+    chunk.
     """
     fam = cfg.family
     if fam not in ("dense", "moe", "audio"):
         out = _prefill_scan_cached(params, cfg, rc, tokens, cache,
-                                   param_specs)
+                                   param_specs, slot)
         return out + (None,) if routing else out
-    pos = cache["pos"]
+    b = tokens.shape[0]
+    pos = jax.lax.dynamic_slice_in_dim(cache["pos"], slot, b)
     x = embed_apply(params["embed"], cfg, tokens)
-    b, c = x.shape[0], x.shape[1]
-    positions = (pos.reshape(b, 1).astype(jnp.int32)
-                 + jnp.arange(c, dtype=jnp.int32)[None])
+    c = x.shape[1]
     if fam == "audio" or not cfg.use_rope:
+        positions = (pos.reshape(b, 1).astype(jnp.int32)
+                     + jnp.arange(c, dtype=jnp.int32)[None])
         x = x + sinusoidal_positions(positions, cfg.d_model).astype(x.dtype)
 
-    def body(x, layer, kv):
-        x, kv2, c = _block_prefill_cached(layer, cfg, rc, x, positions, pos,
-                                          kv, moe_mlp=(fam == "moe"))
-        return x, (kv2, c)
-
-    key = stacked_key(cfg)
-    x, (kv_out, counts) = sr.stream_layers(
-        body, x, params[key], param_specs[key], n_layers=cfg.n_layers,
-        prefetch_depth=rc.sr_prefetch_depth, granularity=rc.sr_granularity,
-        mode="infer", remat=False, stacked_extras=cache["kv"],
-        unroll=rc.scan_unroll)
+    x, (kv_new, counts) = _layers_cached(params, cfg, rc, x, pos,
+                                         cache["kv"], param_specs, row0=slot)
     new_cache = dict(cache)
-    new_cache["kv"] = kv_out
-    new_cache["pos"] = pos + c
+    new_cache["kv"] = attn_lib.write_kv(cache["kv"], *kv_new, pos, row0=slot)
+    new_cache["pos"] = jax.lax.dynamic_update_slice_in_dim(
+        cache["pos"], pos + c, slot, axis=0)
     x = rmsnorm(params["ln_f"], x, cfg.norm_eps)
     logits = unembed_apply(params["embed"], cfg, x)
     if routing:
@@ -654,15 +635,24 @@ def prefill_step_cached(params: Dict, cfg: ModelConfig, rc: RunConfig,
     return logits, new_cache
 
 
-def _prefill_scan_cached(params, cfg, rc, tokens, cache, param_specs):
-    """Sequential-family prefill: scan decode_step over the chunk in-graph."""
+def _prefill_scan_cached(params, cfg, rc, tokens, cache, param_specs, slot):
+    """Sequential-family prefill: scan decode_step over the chunk in-graph,
+    on the slice of rows ``slot`` on."""
+    b = tokens.shape[0]
+    axes = cache_batch_axes(cfg, rc)
+    rows = jax.tree_util.tree_map(
+        lambda a, ax: jax.lax.dynamic_slice_in_dim(a, slot, b, axis=ax),
+        cache, axes)
 
-    def step(cache, tok):
-        logits, cache = decode_step(params, cfg, rc, tok[:, None], cache,
-                                    param_specs)
-        return cache, logits
+    def step(rows, tok):
+        logits, rows = decode_step(params, cfg, rc, tok[:, None], rows,
+                                   param_specs)
+        return rows, logits
 
-    cache, ls = jax.lax.scan(step, cache, jnp.moveaxis(tokens, -1, 0))
+    rows, ls = jax.lax.scan(step, rows, jnp.moveaxis(tokens, -1, 0))
+    cache = jax.tree_util.tree_map(
+        lambda a, r, ax: jax.lax.dynamic_update_slice_in_dim(
+            a, r.astype(a.dtype), slot, axis=ax), cache, rows, axes)
     # ls: [C, B, 1, V] -> [B, C, V]
     logits = jnp.moveaxis(ls[:, :, 0], 0, 1)
     return logits, cache

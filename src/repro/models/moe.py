@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.models import attention as attn
+from repro.models import attention as attn, transformer
 from repro.models.layers import (dense_init, pdtype, rmsnorm, rmsnorm_init)
 
 
@@ -302,32 +302,17 @@ def moe_block_apply(params: Dict, cfg: ModelConfig, x: jnp.ndarray,
 
 
 def moe_block_decode_paged(params: Dict, cfg: ModelConfig, x: jnp.ndarray,
-                           pos: jnp.ndarray, kv: Dict, *, batch_axes,
-                           page_axes, fuse_qkv: bool = True,
-                           kv_block: int = 2048):
-    """Single-token decode against a page-sharded cache (see
-    transformer.block_decode_paged); also returns the MoE layer's routing
-    counts."""
-    h = rmsnorm(params["ln_attn"], x, cfg.norm_eps)
-    positions = jnp.broadcast_to(
-        jnp.asarray(pos, jnp.int32).reshape(-1, 1), (x.shape[0], 1))
-    q, kk, v = attn.qkv_project(params["attn"], cfg, h, positions,
-                                fuse_qkv=fuse_qkv)
-    if "k_scale" in kv:
-        o, k_pages, v_pages, k_scale, v_scale = attn.paged_decode_attention(
-            q, kv["k"], kv["v"], kk, v, pos, batch_axes=batch_axes,
-            page_axes=page_axes, kv_block=kv_block,
-            k_scale=kv["k_scale"], v_scale=kv["v_scale"])
-        kv_out = {"k": k_pages, "v": v_pages, "k_scale": k_scale,
-                  "v_scale": v_scale}
-    else:
-        o, k_pages, v_pages = attn.paged_decode_attention(
-            q, kv["k"], kv["v"], kk, v, pos, batch_axes=batch_axes,
-            page_axes=page_axes, kv_block=kv_block)
-        kv_out = {"k": k_pages, "v": v_pages}
-    x = x + o.reshape(x.shape[0], 1, cfg.q_dim) @ params["attn"]["wo"]
+                           pos: jnp.ndarray, kv: Dict, layer, *, row0=0,
+                           batch_axes=None, page_axes=None,
+                           fuse_qkv: bool = True):
+    """One block over C new tokens per row against a page-sharded stacked
+    cache (see transformer.block_decode_paged); also returns the MoE
+    layer's routing counts."""
+    x, kv_new = transformer.attn_sublayer_cached(
+        params, cfg, x, pos, kv, layer, row0=row0, batch_axes=batch_axes,
+        page_axes=page_axes, fuse_qkv=fuse_qkv)
     h = rmsnorm(params["ln_mlp"], x, cfg.norm_eps)
     y, counts = moe_apply_held(params["moe"], cfg, h,
                                dp_axes=batch_axes or "data")
-    return x + y, kv_out, counts
+    return x + y, kv_new, counts
 

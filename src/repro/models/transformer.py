@@ -63,64 +63,47 @@ def block_apply(params: Dict, cfg: ModelConfig, x: jnp.ndarray,
     return x
 
 
-def block_decode(params: Dict, cfg: ModelConfig, x: jnp.ndarray,
-                 pos: jnp.ndarray, kv_cache: Tuple[jnp.ndarray, jnp.ndarray],
-                 *, fuse_qkv: bool = True, kv_block: int = 2048):
-    """Single-token decode. x: [B, 1, d]; kv_cache: ([B,Smax,Hkv,D], ...).
+def attn_sublayer_cached(params: Dict, cfg: ModelConfig, x: jnp.ndarray,
+                         pos: jnp.ndarray, kv: Dict, layer, *, row0=0,
+                         batch_axes=None, page_axes=None,
+                         fuse_qkv: bool = True):
+    """Attention sublayer of C new tokens per row against a stacked paged
+    cache, which it reads and does not write.
 
-    Writes the new KV at ``pos`` then attends over [0, pos]."""
-    k_cache, v_cache = kv_cache
+    x: [B, C, d], row b's tokens at positions ``pos[b] + i``; kv:
+    {"k","v"} each [L, Bt, n_pages, page, Hkv, D] (int8 caches add
+    "k_scale"/"v_scale"), of which row ``row0 + b`` of layer ``layer``
+    is read (see attention.cached_attention). Returns (x + attention,
+    (k, v) [B, C, Hkv, D]): the new tokens' K/V in the dtype the cache
+    is read in, for attention.write_kv.
+    """
+    b, c = x.shape[0], x.shape[1]
     h = rmsnorm(params["ln_attn"], x, cfg.norm_eps)
-    positions = jnp.broadcast_to(pos, (x.shape[0], 1)).astype(jnp.int32)
-    q, k, v = attn.qkv_project(params["attn"], cfg, h, positions,
+    positions = (jnp.asarray(pos, jnp.int32).reshape(-1, 1)
+                 + jnp.arange(c, dtype=jnp.int32)[None])
+    q, k, v = attn.qkv_project(params["attn"], cfg, h,
+                               jnp.broadcast_to(positions, (b, c)),
                                fuse_qkv=fuse_qkv)
-    k_cache = jax.lax.dynamic_update_slice(
-        k_cache, k.astype(k_cache.dtype), (0, pos, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(
-        v_cache, v.astype(v_cache.dtype), (0, pos, 0, 0))
-    o = attn.decode_attention(q, k_cache, v_cache, kv_len=pos + 1,
-                              kv_block=kv_block,
+    dt = attn.kv_read_dtype(kv)
+    k, v = k.astype(dt), v.astype(dt)
+    o = attn.cached_attention(q, kv, k, v, pos, layer=layer, row0=row0,
+                              batch_axes=batch_axes, page_axes=page_axes,
                               logit_softcap=cfg.attn_logit_softcap)
-    x = x + o.reshape(x.shape[0], 1, cfg.q_dim) @ params["attn"]["wo"]
-    h = rmsnorm(params["ln_mlp"], x, cfg.norm_eps)
-    x = x + mlp_apply(params["mlp"], cfg, h)
-    return x, (k_cache, v_cache)
+    return x + o.reshape(b, c, cfg.q_dim) @ params["attn"]["wo"], (k, v)
 
 
 def block_decode_paged(params: Dict, cfg: ModelConfig, x: jnp.ndarray,
-                       pos: jnp.ndarray, kv: Dict, *, batch_axes, page_axes,
-                       fuse_qkv: bool = True, kv_block: int = 2048):
-    """Single-token decode against a page-sharded cache.
-
-    kv: {"k","v"} each [B, n_pages, page, Hkv, D] sharded over
-    (batch_axes, page_axes); int8 caches carry sibling "k_scale"/
-    "v_scale" leaves [B, n_pages, Hkv] (see models/kv_quant.py). The
-    attention (and the KV write) run distributed via
-    paged_decode_attention — no cache resharding.
-    """
-    h = rmsnorm(params["ln_attn"], x, cfg.norm_eps)
-    positions = jnp.broadcast_to(
-        jnp.asarray(pos, jnp.int32).reshape(-1, 1), (x.shape[0], 1))
-    q, k, v = attn.qkv_project(params["attn"], cfg, h, positions,
-                               fuse_qkv=fuse_qkv)
-    if "k_scale" in kv:
-        o, k_pages, v_pages, k_scale, v_scale = attn.paged_decode_attention(
-            q, kv["k"], kv["v"], k, v, pos, batch_axes=batch_axes,
-            page_axes=page_axes, kv_block=kv_block,
-            logit_softcap=cfg.attn_logit_softcap,
-            k_scale=kv["k_scale"], v_scale=kv["v_scale"])
-        kv_out = {"k": k_pages, "v": v_pages, "k_scale": k_scale,
-                  "v_scale": v_scale}
-    else:
-        o, k_pages, v_pages = attn.paged_decode_attention(
-            q, kv["k"], kv["v"], k, v, pos, batch_axes=batch_axes,
-            page_axes=page_axes, kv_block=kv_block,
-            logit_softcap=cfg.attn_logit_softcap)
-        kv_out = {"k": k_pages, "v": v_pages}
-    x = x + o.reshape(x.shape[0], 1, cfg.q_dim) @ params["attn"]["wo"]
+                       pos: jnp.ndarray, kv: Dict, layer, *, row0=0,
+                       batch_axes=None, page_axes=None,
+                       fuse_qkv: bool = True):
+    """One block over C new tokens per row (decode: C = 1) against a
+    page-sharded stacked cache; returns (x, new (k, v)) as
+    :func:`attn_sublayer_cached` does."""
+    x, kv_new = attn_sublayer_cached(params, cfg, x, pos, kv, layer,
+                                     row0=row0, batch_axes=batch_axes,
+                                     page_axes=page_axes, fuse_qkv=fuse_qkv)
     h = rmsnorm(params["ln_mlp"], x, cfg.norm_eps)
-    x = x + mlp_apply(params["mlp"], cfg, h)
-    return x, kv_out
+    return x + mlp_apply(params["mlp"], cfg, h), kv_new
 
 
 def cross_block_apply(params: Dict, cfg: ModelConfig, x: jnp.ndarray,

@@ -26,10 +26,10 @@ The paper's storage-expansion loop, at request granularity:
 The hot path is device-resident:
 
  * prefill — chunked multi-token ingestion (``models.model.
-   prefill_step_cached``): each chunk is one jitted dispatch that slices
-   the request's slot out of the batch cache, writes the chunk's K/V
-   in-graph (``dynamic_update_slice``) and splices the slot back — no
-   per-token dispatch, no host-side cache surgery.
+   prefill_step_cached``): each chunk is one jitted dispatch that reads
+   the request's slot row of the batch cache in place and scatters the
+   chunk's K/V into it in-graph — no per-token dispatch, no host-side
+   cache surgery.
  * decode — one jitted dispatch per tick that runs the page-sharded
    ``decode_step`` for every slot AND samples the next token on device
    (argmax, or inverse-CDF categorical sampling via the jax PRNG — see
@@ -207,11 +207,12 @@ _RESTORABLE_FAMILIES = ("dense", "moe", "audio")
 
 # Layer-scan unroll of the hot path. Unrolling saves per-layer loop
 # overhead, which dominates a small model's step; but each unrolled layer
-# keeps its own transients of its cache slice live, and a factor that does
-# not divide the layer count slices the remainder out of every cache leaf
-# and weight stack. Compiled for a v5e chip, the 28-layer qwen3-1.7b
-# decode step at 8 slots x 4096 tokens (128 MiB of cache per layer) takes
-# 3.88 GiB of temp at unroll 1, 4.13 at 2, 6.51 at 7 and 10.36 at 8.
+# keeps its own attention transients live, and a factor that does not
+# divide the layer count slices the remainder out of every cache leaf and
+# weight stack. Compiled for a v5e chip, the 28-layer qwen3-1.7b decode
+# step at 8 slots x 4096 tokens (128 MiB of cache per layer, read in
+# place) takes 0.002 GiB of temp at unroll 1, 0.18 at 2, 0.65 at 7 and
+# 2.99 at 8.
 _UNROLL_MAX = 8
 _UNROLL_CACHE_BYTES = 128 << 20     # cache the unrolled layers may span
 
@@ -407,7 +408,7 @@ class ServingEngine:
         # sharded serving: build the (data, model) mesh the config asks
         # for and activate it around every jitted dispatch — params and
         # the paged KV cache shard over the model axis, and
-        # paged_decode_attention's shard_map body engages (the page axis
+        # cached_attention's shard_map body engages (the page axis
         # carries the tensor parallelism; see models/attention.py)
         self.mesh = None
         mesh_shape = config.resolved_mesh_shape
@@ -573,32 +574,24 @@ class ServingEngine:
                             new_pos, last_tokens, key, sample):
         """One prefill chunk for one slot, entirely in-graph.
 
-        Slices the slot out of the batch cache, pins the slot position to
-        the chunk start (a reused slot's device pos is stale — decode
-        advances every row each tick), runs the chunked cache-writing
-        prefill, and splices the slot back (dynamic_update_slice along
-        each leaf's batch axis). Only the final chunk (``sample=True``,
-        static) samples the last-position token on device — one PRNG
-        split per request, so sampled streams do not depend on the chunk
-        size. Other slots never observe the prefill (continuous-batching
-        isolation). A model with experts adds the chunk's routing counts
-        as the last output of either form.
+        Pins the slot position to the chunk start (a reused slot's device
+        pos is stale -- decode advances every row each tick), runs the
+        chunked cache-writing prefill on the slot's row of the batch
+        cache (which it reads in place and writes the chunk into), and
+        sets the slot's position to ``new_pos``. Only the final chunk
+        (``sample=True``, static) samples the last-position token on
+        device -- one PRNG split per request, so sampled streams do not
+        depend on the chunk size. Other slots never observe the prefill
+        (continuous-batching isolation). A model with experts adds the
+        chunk's routing counts as the last output of either form.
         """
-        baxes = self._batch_axes()
-        cache1 = jax.tree_util.tree_map(
-            lambda a, ax: jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=ax),
-            cache, baxes)
-        cache1["pos"] = jnp.full((1,), pos0, jnp.int32)
-        logits, cache1, counts = M.prefill_step_cached(
-            params, self.cfg, self.hot_rc, tokens, cache1, self.pspecs,
-            routing=True)
+        cache = dict(cache, pos=cache["pos"].at[slot].set(pos0))
+        logits, cache, counts = M.prefill_step_cached(
+            params, self.cfg, self.hot_rc, tokens, cache, self.pspecs,
+            routing=True, slot=slot)
         routing = () if counts is None else (
             jnp.concatenate([counts[:1], jnp.zeros((2,), jnp.int32)]),)
-        cache1["pos"] = jnp.full((1,), new_pos, jnp.int32)
-        cache = jax.tree_util.tree_map(
-            lambda a, a1, ax: jax.lax.dynamic_update_slice_in_dim(
-                a, a1.astype(a.dtype), slot, axis=ax),
-            cache, cache1, baxes)
+        cache["pos"] = cache["pos"].at[slot].set(new_pos)
         if not sample:
             return (cache, *routing)
         row = M.last_token_logits(logits)            # [1, V]
@@ -641,14 +634,7 @@ class ServingEngine:
     def _batch_axes(self):
         """Locate each cache leaf's batch axis (differencing two shapes)."""
         if not hasattr(self, "_baxes"):
-            a = M.cache_init(self.cfg, self.rc, 2, max_seq=self.max_seq,
-                             as_shape=True)
-            b = M.cache_init(self.cfg, self.rc, 3, max_seq=self.max_seq,
-                             as_shape=True)
-            self._baxes = jax.tree_util.tree_map(
-                lambda x, y: next(i for i, (p, q) in
-                                  enumerate(zip(x.shape, y.shape))
-                                  if p != q), a, b)
+            self._baxes = M.cache_batch_axes(self.cfg, self.rc)
         return self._baxes
 
     def _prefill_slot(self, req: Request, slot: int,

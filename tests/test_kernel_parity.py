@@ -6,7 +6,7 @@ the fixed-case sweep in ``test_kernels.py``. Kernels execute in interpret
 mode on CPU (the same code path Mosaic compiles on TPU).
 
 Also covers the serving engine's dispatch split: the single-rank fast
-path in ``paged_decode_attention`` vs the shard_map path must be
+path in ``attention.cached_attention`` vs the shard_map path must be
 numerically identical (``force_shard_map`` pins the latter on).
 """
 import jax
@@ -23,7 +23,7 @@ from repro.kernels.hdm_stream.ops import stream_matmul
 from repro.kernels.hdm_stream.ref import paged_matmul_ref
 from repro.kernels.mamba2_scan.ops import ssd
 from repro.kernels.mamba2_scan.ref import ssd_scan_ref
-from repro.models import kv_quant as kvq
+from repro.models import attention as attn, kv_quant as kvq
 
 
 def _tol(dtype):
@@ -33,6 +33,25 @@ def _tol(dtype):
 
 def _key(seed, i=0):
     return jax.random.fold_in(jax.random.PRNGKey(seed), i)
+
+
+def _decode_layer(q, kp, vp, nk, nv, pos, *, k_scale=None, v_scale=None,
+                  **kw):
+    """One layer's decode tick as the serving programs run it:
+    ``cached_attention`` over the stored tokens plus the new one, then
+    ``write_kv`` of the new token at ``pos``. pages: [B, P, page, Hkv, D]
+    (int8 with ``k_scale``/``v_scale`` [B, P, Hkv]). Returns (out, k, v)
+    or, int8, (out, k, v, k_scale, v_scale)."""
+    kv = {"k": kp[None], "v": vp[None]}
+    if k_scale is not None:
+        kv.update(k_scale=k_scale[None], v_scale=v_scale[None])
+    dt = attn.kv_read_dtype(kv)
+    nk, nv = nk.astype(dt), nv.astype(dt)
+    out = attn.cached_attention(q, kv, nk, nv, pos, layer=0, **kw)
+    kv = attn.write_kv(kv, nk[None], nv[None], pos)
+    names = ("k", "v", "k_scale", "v_scale") if k_scale is not None \
+        else ("k", "v")
+    return (out,) + tuple(kv[n][0] for n in names)
 
 
 DTYPES = [jnp.float32, jnp.bfloat16]
@@ -148,9 +167,10 @@ def test_hdm_stream_matmul_parity(shape, dtype, seed):
 def test_paged_decode_fast_path_matches_shard_map(shape, lendraw, seed):
     """The serving decode tick picks the single-rank fast path when the
     mesh axes are degenerate; both it and the rank-masked shard_map body
-    must produce identical outputs AND identical updated page buffers."""
+    must produce identical outputs AND identical updated page buffers.
+    Both store through the one ``write_kv``; its sharded form is checked
+    against a single device in ``tests/test_sharded_serving.py``."""
     from repro.launch.mesh import make_host_mesh
-    from repro.models.attention import paged_decode_attention
 
     B, H, Hkv, D, P, page = shape
     q = jax.random.normal(_key(seed, 0), (B, 1, H, D), jnp.float32)
@@ -163,11 +183,11 @@ def test_paged_decode_fast_path_matches_shard_map(shape, lendraw, seed):
     pos = jnp.asarray([(lendraw + 7 * i) % (P * page) for i in range(B)],
                       jnp.int32)
     with jax.set_mesh(make_host_mesh()):
-        fast = paged_decode_attention(q, kp, vp, nk, nv, pos,
-                                      batch_axes="data", page_axes="model")
-        smap = paged_decode_attention(q, kp, vp, nk, nv, pos,
-                                      batch_axes="data", page_axes="model",
-                                      force_shard_map=True)
+        fast = _decode_layer(q, kp, vp, nk, nv, pos,
+                              batch_axes="data", page_axes="model")
+        smap = _decode_layer(q, kp, vp, nk, nv, pos,
+                              batch_axes="data", page_axes="model",
+                              force_shard_map=True)
     for a, b, name in zip(fast, smap, ("out", "k_pages", "v_pages")):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-5, rtol=1e-5, err_msg=name)
@@ -291,7 +311,6 @@ def test_paged_decode_int8_fast_path_matches_shard_map(shape, lendraw, seed):
     monotone-scale requantization makes non-owner ranks' masked writes
     round-trip bit-exactly, so the combine cannot drift)."""
     from repro.launch.mesh import make_host_mesh
-    from repro.models.attention import paged_decode_attention
 
     B, H, Hkv, D, P, page = shape
     q = jax.random.normal(_key(seed, 0), (B, 1, H, D), jnp.float32)
@@ -304,13 +323,13 @@ def test_paged_decode_int8_fast_path_matches_shard_map(shape, lendraw, seed):
     pos = jnp.asarray([(lendraw + 7 * i) % (P * page) for i in range(B)],
                       jnp.int32)
     with jax.set_mesh(make_host_mesh()):
-        fast = paged_decode_attention(q, kq, vq, nk, nv, pos,
-                                      batch_axes="data", page_axes="model",
-                                      k_scale=ks, v_scale=vs)
-        smap = paged_decode_attention(q, kq, vq, nk, nv, pos,
-                                      batch_axes="data", page_axes="model",
-                                      force_shard_map=True,
-                                      k_scale=ks, v_scale=vs)
+        fast = _decode_layer(q, kq, vq, nk, nv, pos,
+                              batch_axes="data", page_axes="model",
+                              k_scale=ks, v_scale=vs)
+        smap = _decode_layer(q, kq, vq, nk, nv, pos,
+                              batch_axes="data", page_axes="model",
+                              force_shard_map=True,
+                              k_scale=ks, v_scale=vs)
     assert len(fast) == 5 and len(smap) == 5
     names = ("out", "k_pages", "v_pages", "k_scale", "v_scale")
     for a, b, name in zip(fast, smap, names):
@@ -345,7 +364,6 @@ def test_paged_decode_two_rank_mesh_matches_single_rank_and_ref(
     path and the exact-softmax oracle on the updated pages. This is the
     sharded serving engine's decode tick, minus the engine."""
     from repro.launch.mesh import make_host_mesh, make_production_mesh
-    from repro.models.attention import paged_decode_attention
 
     B, H, Hkv, D, P, page = shape
     q = jax.random.normal(_key(seed, 0), (B, 1, H, D), jnp.float32)
@@ -356,12 +374,12 @@ def test_paged_decode_two_rank_mesh_matches_single_rank_and_ref(
     pos = jnp.asarray([(lendraw + 7 * i) % (P * page) for i in range(B)],
                       jnp.int32)
     with jax.set_mesh(make_production_mesh(shape=(1, 2))):
-        two = paged_decode_attention(q, kp, vp, nk, nv, pos,
-                                     batch_axes="data", page_axes="model",
-                                     force_shard_map=True)
+        two = _decode_layer(q, kp, vp, nk, nv, pos,
+                             batch_axes="data", page_axes="model",
+                             force_shard_map=True)
     with jax.set_mesh(make_host_mesh()):
-        one = paged_decode_attention(q, kp, vp, nk, nv, pos,
-                                     batch_axes="data", page_axes="model")
+        one = _decode_layer(q, kp, vp, nk, nv, pos,
+                             batch_axes="data", page_axes="model")
     for a, b, name in zip(two, one, ("out", "k_pages", "v_pages")):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-5, rtol=1e-5, err_msg=name)
@@ -394,7 +412,6 @@ def test_paged_decode_int8_two_rank_mesh_matches_single_rank(
     reproduce the single-rank fast path bit-for-bit on the int8 buffers
     (monotone-scale requantization) and bitwise-close on the floats."""
     from repro.launch.mesh import make_host_mesh, make_production_mesh
-    from repro.models.attention import paged_decode_attention
 
     B, H, Hkv, D, P, page = shape
     q = jax.random.normal(_key(seed, 0), (B, 1, H, D), jnp.float32)
@@ -407,14 +424,14 @@ def test_paged_decode_int8_two_rank_mesh_matches_single_rank(
     pos = jnp.asarray([(lendraw + 7 * i) % (P * page) for i in range(B)],
                       jnp.int32)
     with jax.set_mesh(make_production_mesh(shape=(1, 2))):
-        two = paged_decode_attention(q, kq, vq, nk, nv, pos,
-                                     batch_axes="data", page_axes="model",
-                                     force_shard_map=True,
-                                     k_scale=ks, v_scale=vs)
+        two = _decode_layer(q, kq, vq, nk, nv, pos,
+                             batch_axes="data", page_axes="model",
+                             force_shard_map=True,
+                             k_scale=ks, v_scale=vs)
     with jax.set_mesh(make_host_mesh()):
-        one = paged_decode_attention(q, kq, vq, nk, nv, pos,
-                                     batch_axes="data", page_axes="model",
-                                     k_scale=ks, v_scale=vs)
+        one = _decode_layer(q, kq, vq, nk, nv, pos,
+                             batch_axes="data", page_axes="model",
+                             k_scale=ks, v_scale=vs)
     assert len(two) == 5 and len(one) == 5
     names = ("out", "k_pages", "v_pages", "k_scale", "v_scale")
     for a, b, name in zip(two, one, names):

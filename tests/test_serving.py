@@ -197,6 +197,128 @@ def test_prefill_step_cached_matches_sequential_decode(mesh_ctx):
         atol=2e-2, rtol=2e-2)
 
 
+# --------------------------------- cache read in place, one write per call
+
+MAX_SEQ = 512                 # two pages of 256: a boundary at 255 -> 256
+
+
+def _gqa_model(group, dtype):
+    cfg = dataclasses.replace(registry.smoke("qwen3-1.7b"), n_layers=2,
+                              n_heads=2 * group, n_kv_heads=2, dtype=dtype)
+    rc = RunConfig(model=cfg, shape=SHAPES["decode_32k"], mesh=MeshConfig())
+    params = M.init_model(jax.random.PRNGKey(3), cfg)
+    import repro.parallel.sharding as shlib
+    return cfg, rc, params, shlib.param_specs(jax.eval_shape(lambda: params))
+
+
+def _ref_call(params, cfg, tokens, k, v, pos):
+    """Reference of one call: each layer writes the rows' new K/V with
+    ``.at[].set`` and attends over the written rows with naive_attention.
+    tokens [B, C]; k/v [L, B, S, Hkv, D]; row b's tokens at pos[b] + i.
+    Returns (logits [B, C, V], k, v)."""
+    from repro.models import attention as A
+    from repro.models.layers import (embed_apply, mlp_apply, rmsnorm,
+                                     unembed_apply)
+    b, c = tokens.shape
+    x = embed_apply(params["embed"], cfg, tokens)
+    for l in range(cfg.n_layers):
+        layer = jax.tree_util.tree_map(lambda a: a[l], params["blocks"])
+        h = rmsnorm(layer["ln_attn"], x, cfg.norm_eps)
+        positions = jnp.asarray(pos)[:, None] + jnp.arange(c)[None]
+        q, kn, vn = A.qkv_project(layer["attn"], cfg, h, positions)
+        rows = []
+        for r in range(b):
+            p = int(pos[r])
+            k = k.at[l, r, p:p + c].set(kn[r].astype(k.dtype))
+            v = v.at[l, r, p:p + c].set(vn[r].astype(v.dtype))
+            # queries padded to the front so the causal mask lines up
+            qr = jnp.zeros((1, p + c) + q.shape[2:], q.dtype)
+            qr = qr.at[0, p:].set(q[r])
+            rows.append(A.naive_attention(qr, k[l, r:r + 1, :p + c],
+                                          v[l, r:r + 1, :p + c])[0, p:])
+        o = jnp.stack(rows).reshape(b, c, cfg.q_dim)
+        x = x + o @ layer["attn"]["wo"]
+        x = x + mlp_apply(layer["mlp"], cfg,
+                          rmsnorm(layer["ln_mlp"], x, cfg.norm_eps))
+    x = rmsnorm(params["ln_f"], x, cfg.norm_eps)
+    return unembed_apply(params["embed"], cfg, x), k, v
+
+
+def _noisy_cache(cfg, rc, rows, pos):
+    """A cache whose every position holds noise, so a read past a row's
+    length or of another row shows in the logits."""
+    cache = M.cache_init(cfg, rc, rows, max_seq=MAX_SEQ)
+    ks = jax.random.split(jax.random.PRNGKey(4), 2)
+    for key, name in zip(ks, ("k", "v")):
+        a = cache["kv"][name]
+        cache["kv"][name] = jax.random.normal(key, a.shape).astype(a.dtype)
+    cache["pos"] = jnp.asarray(pos, jnp.int32)
+    return cache
+
+
+def _flat(a):
+    return a.reshape(a.shape[:2] + (-1,) + a.shape[4:])
+
+
+def _check(got_logits, want_logits, cache, k, v, tol):
+    np.testing.assert_allclose(np.asarray(got_logits, np.float32),
+                               np.asarray(want_logits, np.float32),
+                               atol=tol, rtol=tol)
+    for name, want in (("k", k), ("v", v)):
+        np.testing.assert_allclose(
+            np.asarray(_flat(cache["kv"][name]), np.float32),
+            np.asarray(want, np.float32), atol=tol, rtol=tol, err_msg=name)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-4),
+                                       ("bfloat16", 5e-2)])
+@pytest.mark.parametrize("group", [2, 16])
+def test_decode_ticks_match_written_cache_reference(mesh_ctx, group, dtype,
+                                                    tol):
+    """Three decode ticks of rows at different positions: one mid-page,
+    one stepping over the page boundary (255 -> 256), one ending at
+    ``max_seq - 1``. After each tick the logits and the whole cache match
+    a reference that writes the token first and attends over the cache."""
+    cfg, rc, params, pspecs = _gqa_model(group, dtype)
+    pos = [17, 254, MAX_SEQ - 3]
+    cache = _noisy_cache(cfg, rc, 3, pos)
+    k, v = _flat(cache["kv"]["k"]), _flat(cache["kv"]["v"])
+    step = jax.jit(lambda p, t, c: M.decode_step(p, cfg, rc, t, c, pspecs))
+    for i in range(3):
+        toks = jnp.asarray([[5 + i], [40 + i], [200 + i]], jnp.int32)
+        want, k, v = _ref_call(params, cfg, toks, k, v,
+                               [p + i for p in pos])
+        got, cache = step(params, toks, cache)
+        _check(got, want, cache, k, v, tol)
+        assert list(np.asarray(cache["pos"])) == [p + i + 1 for p in pos]
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-4),
+                                       ("bfloat16", 5e-2)])
+@pytest.mark.parametrize("group", [2, 16])
+@pytest.mark.parametrize("slot,pos0", [(1, 200), (2, MAX_SEQ - 128)])
+def test_prefill_chunk_matches_written_cache_reference(mesh_ctx, group,
+                                                       dtype, tol, slot,
+                                                       pos0):
+    """One 128-token chunk into one row of a three-row cache, across the
+    page boundary or up to ``max_seq - 1``: the chunk's logits, and the
+    whole cache (other rows untouched), match the reference."""
+    cfg, rc, params, pspecs = _gqa_model(group, dtype)
+    pos = [60, 90, 120]
+    pos[slot] = pos0
+    cache = _noisy_cache(cfg, rc, 3, pos)
+    k, v = _flat(cache["kv"]["k"]), _flat(cache["kv"]["v"])
+    toks = jax.random.randint(jax.random.PRNGKey(5), (1, 128), 1, 256)
+    want, kr, vr = _ref_call(params, cfg, toks, k[:, slot:slot + 1],
+                             v[:, slot:slot + 1], [pos0])
+    k, v = k.at[:, slot:slot + 1].set(kr), v.at[:, slot:slot + 1].set(vr)
+    got, cache = jax.jit(lambda p, t, c, s: M.prefill_step_cached(
+        p, cfg, rc, t, c, pspecs, slot=s))(params, toks, cache, slot)
+    _check(got, want, cache, k, v, tol)
+    pos[slot] += 128
+    assert list(np.asarray(cache["pos"])) == pos
+
+
 def test_engine_chunked_prefill_matches_legacy_greedy(mesh_ctx):
     """Multi-chunk prefill + fused on-device sampling must emit the same
     greedy tokens as the pre-rewrite per-token host path."""

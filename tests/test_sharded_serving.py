@@ -10,6 +10,10 @@ The sharded cases need forced host devices
 job sets it); on a single-device interpreter they skip, never fail.
 """
 import dataclasses
+import os
+import subprocess
+import sys
+import textwrap
 
 import jax
 import numpy as np
@@ -185,6 +189,95 @@ def test_sharded_engine_with_tier_token_identity():
         ref = _greedy_tokens(_build_engine(tier=True))
     assert toks == ref
     assert isinstance(eng.tier, ShardedTier)
+
+
+_SHARDED_WRITE_SCRIPT = textwrap.dedent("""
+    import contextlib, os, re
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import dataclasses
+    import jax, numpy as np
+    from repro.configs import registry
+    from repro.launch.mesh import make_host_mesh
+    from repro.configs.base import MeshConfig, RunConfig, SHAPES
+    from repro.models import model as M
+    from repro.serving.config import ServeConfig
+    from repro.serving.engine import ServingEngine
+
+    cfg = registry.smoke("qwen3-1.7b")
+    rc = dataclasses.replace(RunConfig(model=cfg, shape=SHAPES["decode_32k"],
+                                       mesh=MeshConfig()), kv_page_size=16)
+    params = M.init_model(jax.random.PRNGKey(0), cfg)
+    caches = []
+    for shape in ((2, 2), ()):
+        host = contextlib.nullcontext() if shape else \\
+            jax.set_mesh(make_host_mesh())
+        with host:
+            eng = ServingEngine(params, cfg, rc, config=ServeConfig(
+                n_slots=2, max_seq=64, prefill_chunk=8, mesh_shape=shape))
+            kv = eng.cache["kv"]
+            noise = jax.random.normal(jax.random.PRNGKey(1), kv["k"].shape)
+            cache = dict(eng.cache, pos=jax.device_put(
+                np.asarray([5, 37], np.int32), eng.cache["pos"].sharding),
+                kv={n: jax.device_put(noise.astype(a.dtype) * (i + 1),
+                                      a.sharding)
+                    for i, (n, a) in enumerate(kv.items())})
+            before = jax.device_get(cache["kv"])     # donated below
+            with eng._mesh_scope():
+                if shape:
+                    txt = eng._decode_fn.lower(
+                        eng.params, cache, eng.last_tokens,
+                        eng.key).compile().as_text()
+                out = eng._decode_fn(eng.params, cache, eng.last_tokens,
+                                     eng.key)
+            caches.append(jax.device_get(out[0]["kv"]))
+    # one layer of one rank's share of a cache leaf, in elements
+    leaf = kv["k"].shape
+    shard = int(np.prod(leaf[1:])) // 4
+    hkv_d = ",".join(map(str, leaf[-2:]))
+    gathered = []
+    for line in txt.splitlines():
+        m = re.search(r"= (.*?) (all-gather|all-to-all|collective-permute)"
+                      r"(-start)?\\(", line)
+        if m:
+            for dims in re.findall(r"\\[([\\d,]*)\\]", m.group(1)):
+                if dims.endswith(hkv_d):
+                    gathered.append(int(np.prod([int(x) for x in
+                                                 dims.split(",")])))
+    assert gathered and max(gathered) < shard, (gathered, shard)
+    # rows 0 and 1 write positions 5 (rank 0's pages) and 37 (rank 1's)
+    written = np.zeros(leaf[:2] + (leaf[2] * leaf[3],), bool)
+    written[:, 0, 5] = written[:, 1, 37] = True
+    for n in caches[0]:
+        a, b, x = (np.asarray(c[n], np.float32).reshape(written.shape
+                                                        + leaf[-2:])
+                   for c in caches + [before])
+        np.testing.assert_array_equal(a[~written], x[~written], err_msg=n)
+        np.testing.assert_array_equal(b[~written], x[~written], err_msg=n)
+        # layer 0's new K/V are the same arithmetic on both meshes; later
+        # layers' follow attention outputs combined across ranks
+        np.testing.assert_array_equal(a[0][written[0]], b[0][written[0]])
+        assert not np.array_equal(a[0][written[0]], x[0][written[0]]), n
+        np.testing.assert_allclose(a[written], b[written], atol=5e-2,
+                                   rtol=5e-2, err_msg=n)
+    print("SHARDED_WRITE_OK", max(gathered), shard)
+""")
+
+
+def test_sharded_decode_writes_in_place_without_gathering_the_cache():
+    """The decode program on a (2, 2) mesh (batch over "data", pages over
+    "model") stores the new tokens with one scatter after the layer scan,
+    partitioned by XLA: no collective in it moves a K/V buffer as large
+    as one layer of one rank's pages (only the new tokens travel), and the
+    cache it writes equals the single-device program's, position for
+    position, with rows whose new token lands on either rank's pages."""
+    env = dict(os.environ,
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    env.pop("XLA_FLAGS", None)
+    res = subprocess.run([sys.executable, "-c", _SHARDED_WRITE_SCRIPT],
+                         capture_output=True, text=True, env=env,
+                         timeout=600)
+    assert "SHARDED_WRITE_OK" in res.stdout, res.stderr[-3000:]
 
 
 # ------------------------------------------------ rank-trace replay
